@@ -9,11 +9,11 @@ mesh key component), and the :func:`repro.core.codesign.plan_pdgemm` model
 terms (compute vs per-hop collective bytes) - so the cross-device
 co-design claim is a measured trajectory, not prose.
 
-Device note: XLA fixes the host device count at first jax init, so
-standalone runs force 8 virtual CPU devices via ``XLA_FLAGS`` *before*
-importing jax, and the ``benchmarks.run`` driver entry re-execs this
-module in a subprocess (the driver process already initialized jax with 1
-device).
+Device note: the sweep runs in the calling process, on the meshes that
+fit the devices it sees; a chip belongs to one process, so no child is
+started. XLA fixes the host device count at first jax init, so a
+standalone CPU rehearsal forces 8 virtual CPU devices via ``XLA_FLAGS``
+*before* importing jax (only when run as ``__main__``).
 
 Standalone:  PYTHONPATH=src python -m benchmarks.bench_distributed_blas \
                  [--fast] [--out benchmarks/out/BENCH_distributed.json]
@@ -24,7 +24,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 
 _N_DEVICES = 8
@@ -39,7 +38,7 @@ def _with_device_flag(flags: str) -> str:
     return f"{flags} {_DEV_FLAG}".strip()
 
 
-if __name__ == "__main__":  # force the virtual mesh before jax initializes
+if __name__ == "__main__":  # CPU rehearsal: virtual devices before jax init
     os.environ["XLA_FLAGS"] = _with_device_flag(os.environ.get("XLA_FLAGS", ""))
 
 import jax
@@ -71,9 +70,8 @@ def sweep(gemm_shapes=None, factor_grid=None, policies=POLICIES, reps=1):
     rows = []
     ndev = jax.device_count()
     if ndev < _N_DEVICES:
-        print(f"WARNING: only {ndev} device(s) visible (want {_N_DEVICES}; "
-              f"XLA_FLAGS must carry {_DEV_FLAG} before jax initializes) - "
-              f"multi-device meshes will be skipped", file=sys.stderr)
+        print(f"note: {ndev} device(s) visible - meshes larger than that "
+              f"are skipped", file=sys.stderr)
     meshes = [(px, py) for px, py in MESHES if px * py <= ndev]
     for px, py in meshes:
         mesh = dblas.make_blas_mesh(px, py)
@@ -160,25 +158,16 @@ def _emit_rows(emit, rec) -> None:
 
 
 def run(emit, fast: bool = True):
-    """benchmarks.run driver entry. The driver process has 1 device, so
-    re-exec this module standalone (subprocess) with the forced-device
-    XLA flag, then emit from its JSON artifact."""
+    """benchmarks.run driver entry: sweep in this process, on the devices
+    it already holds, then emit from the JSON artifact."""
     out = _OUT_DEFAULT
     os.makedirs(os.path.dirname(out), exist_ok=True)
-    env = dict(os.environ,
-               XLA_FLAGS=_with_device_flag(os.environ.get("XLA_FLAGS", "")),
-               PYTHONPATH="src" + (os.pathsep + os.environ["PYTHONPATH"]
-                                   if os.environ.get("PYTHONPATH") else ""))
-    cmd = [sys.executable, "-m", "benchmarks.bench_distributed_blas",
-           "--out", out] + (["--fast"] if fast else [])
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    r = subprocess.run(cmd, env=env, cwd=root, capture_output=True,
-                       text=True, timeout=3600)
-    if r.returncode != 0:
-        raise RuntimeError(
-            f"distributed sweep subprocess failed:\n{r.stdout}\n{r.stderr}")
-    with open(out) as f:
-        rec = json.load(f)
+    rows = sweep(gemm_shapes=FAST_GEMM if fast else None,
+                 factor_grid=FAST_FACTOR if fast else None,
+                 reps=1 if fast else 2)
+    rec = record(rows)
+    with open(out, "w") as f:
+        json.dump(rec, f, indent=2)
     _emit_rows(emit, rec)
     emit("distributed_blas,device_count", rec["device_count"], "devices")
     emit("distributed_blas,json", out, "path")
@@ -189,6 +178,8 @@ def main() -> None:
     ap.add_argument("--out", default=_OUT_DEFAULT)
     ap.add_argument("--fast", action="store_true", help="CI-sized grid")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = sweep(gemm_shapes=FAST_GEMM if args.fast else None,
                  factor_grid=FAST_FACTOR if args.fast else None,
                  reps=1 if args.fast else 2)

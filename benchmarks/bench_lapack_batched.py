@@ -140,6 +140,8 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="small grid (CI-sized)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = sweep(**FAST_GRID) if args.fast else sweep()
     rec = record(rows)
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)

@@ -126,6 +126,8 @@ def main() -> None:
     ap.add_argument("--out-dir", default="benchmarks/out")
     ap.add_argument("--fast", action="store_true", help="CI-sized grid")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     os.makedirs(args.out_dir, exist_ok=True)
     reg = Registry(path=os.path.join(args.out_dir, "tune_registry.json"))
     rows = sweep(reg, gemm_shapes=FAST_GEMM if args.fast else None,

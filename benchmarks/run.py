@@ -50,6 +50,9 @@ def main() -> None:
             print(f"{name:18s} {modpath}")
         return
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     def emit(name, value, unit):
         print(f"{name},{value},{unit}", flush=True)
 

@@ -161,7 +161,7 @@ def _site_of(eqn) -> Optional[str]:
     """``repro/<path>.py:<function>`` for one eqn, or None if unknown."""
     try:
         from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
     except Exception:
         frame = None
     if frame is None:

@@ -28,7 +28,7 @@ def _source_location(eqn) -> Optional[str]:
     tracer did not keep source info (private API - degrade, never fail)."""
     try:
         from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
         if frame is None:
             return None
         return f"{frame.file_name}:{frame.start_line}"

@@ -1,7 +1,7 @@
 """analysis.check / check_surface: trace, lint, and report.
 
 ``check(fn, *args)`` is the one entry point: it traces ``fn`` with
-``jax.make_jaxpr`` (no execution) under ``jax.experimental.enable_x64``
+``jax.make_jaxpr`` (no execution) under ``jax.enable_x64``
 - the canonical lint mode, where silent f64 promotion (DF001) and 64-bit
 index dtypes (KL003) are *representable* instead of being clamped away -
 records every dispatcher :class:`~repro.tune.dispatch.Resolution` the
@@ -118,8 +118,7 @@ def merge_reports(reports: Sequence[AnalysisReport],
 # ------------------------------ tracing helpers -----------------------------
 
 def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def _leaves(args, kw):

@@ -18,10 +18,11 @@ under the adaptive repetition controller of :mod:`repro.tune.measure`:
   fit ``FPUSpec.depths``.
 
 The fitted sections replace their counterparts in a *base* spec (default:
-``cpu-host`` for the CPU backend) - power/area stays the base's, since
-wall-clock micro-benchmarks cannot observe pJ/flop or die area - and the
-result is a frozen, JSON-serializable :class:`~repro.arch.spec.MachineSpec`
-named ``calibrated-<backend>`` that is registered into the machine
+chosen by device kind, :data:`BASE_BY_DEVICE_KIND`) - power/area stays
+the base's, since wall-clock micro-benchmarks cannot observe pJ/flop or
+die area - and the result is a frozen, JSON-serializable
+:class:`~repro.arch.spec.MachineSpec` named ``calibrated-<backend>`` that
+is registered into the machine
 registry (``arch.get("calibrated-cpu")``) and can round-trip through
 ``save``/``load`` like any other spec.
 
@@ -44,6 +45,11 @@ from repro.arch.spec import MachineSpec, OP_CLASSES
 # the acceptance loop; the best rungs are exact fits up to rep noise, so
 # this bounds measurement spread, not model error).
 CALIBRATION_TOLERANCE = 0.35
+
+# base spec per device kind (``jax.devices()[0].device_kind``): the spec
+# whose unmeasured sections (power/area, geometry) a calibration keeps. A
+# device missing here has no base to stand on and is refused.
+BASE_BY_DEVICE_KIND = {"cpu": "cpu-host", "TPU v5 lite": "tpu-like"}
 
 # fitted pipeline depths are clamped into this range: >= 1 by FPUSpec's
 # validation, <= 64 so one noisy chain sample cannot declare an absurd pipe
@@ -144,7 +150,8 @@ def run_microbenchmarks(gemm_sizes: Sequence[int] = (64, 128, 256),
         chains[cls] = {"iters": int(chain_iters), "measurement": m,
                        "latency_s": m.seconds_median / int(chain_iters)}
 
-    return {"backend": jax.default_backend(), "gemm": gemm,
+    return {"backend": jax.default_backend(),
+            "device_kind": jax.devices()[0].device_kind, "gemm": gemm,
             "stream": stream, "chains": chains}
 
 
@@ -164,11 +171,16 @@ def fit_machine(results: Mapping[str, Any],
                 base: Optional[MachineSpec] = None,
                 name: Optional[str] = None) -> MachineSpec:
     """Fit FPU/Memory/PE parameters from :func:`run_microbenchmarks`
-    evidence into a copy of ``base`` (default: ``cpu-host`` on the CPU
-    backend, ``tpu-like`` otherwise)."""
+    evidence into a copy of ``base`` (default: the spec
+    :data:`BASE_BY_DEVICE_KIND` names for the measured device kind)."""
     backend = results["backend"]
     if base is None:
-        base = _registry.get("cpu-host" if backend == "cpu" else "tpu-like")
+        kind = results["device_kind"]
+        if kind not in BASE_BY_DEVICE_KIND:
+            raise ValueError(f"no base machine spec for device kind "
+                             f"{kind!r}; known kinds: "
+                             f"{sorted(BASE_BY_DEVICE_KIND)}")
+        base = _registry.get(BASE_BY_DEVICE_KIND[kind])
     name = name or f"calibrated-{backend}"
 
     peak = max(r["flops"] / r["measurement"].seconds_median

@@ -33,7 +33,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.distributed.collectives import (CollectiveRecord, emit_record,
                                            ring_bcast)
@@ -70,7 +69,7 @@ def _pad2(a: jnp.ndarray, r0: int, r1: int) -> jnp.ndarray:
     return jnp.pad(a, ((0, p0), (0, p1)))
 
 
-def _local_update(ap, bp, res, interpret: bool):
+def _local_update(ap, bp, res, interpret: Optional[bool]):
     """One SUMMA panel update on the resolved path (jnp or Pallas) - the
     exact executor every other policy-dispatched GEMM uses."""
     from repro.tune.dispatch import _gemm_exec      # lazy: avoid cycle
@@ -80,7 +79,7 @@ def _local_update(ap, bp, res, interpret: bool):
 def pdgemm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh,
            c: Optional[jnp.ndarray] = None, alpha=1.0, beta=0.0,
            policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-           interpret: bool = True, registry=None) -> jnp.ndarray:
+           interpret: Optional[bool] = None, registry=None) -> jnp.ndarray:
     """C <- alpha * A B + beta * C, SUMMA-sharded over a ("x", "y") mesh.
 
     Parameters
@@ -138,9 +137,9 @@ def pdgemm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh,
               "dtype": jnp.dtype(a.dtype).name}))
     inner = functools.partial(_summa_inner, px=px, py=py, kf=kf, res=res,
                               interpret=interpret)
-    f = shard_map(inner, mesh=mesh,
-                  in_specs=(P("x", "y"), P("x", "y")),
-                  out_specs=P("x", "y"), check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh,
+                      in_specs=(P("x", "y"), P("x", "y")),
+                      out_specs=P("x", "y"), check_vma=False)
     out = f(a_p, b_p)[:m, :n]
     out = alpha * out
     if c is not None:
@@ -148,7 +147,8 @@ def pdgemm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh,
     return out
 
 
-def _summa_inner(a, b, *, px: int, py: int, kf: int, res, interpret: bool):
+def _summa_inner(a, b, *, px: int, py: int, kf: int, res,
+                 interpret: Optional[bool]):
     """Per-device SUMMA body: a (m/px, k/py) A shard holding coarse k-panel
     ``j``; b (k/px, n/py) B shard holding coarse k-panel ``i``. Fine panel
     ``g`` lives at A coarse ``g // px`` offset ``(g % px) * kf`` and B
@@ -166,7 +166,7 @@ def _summa_inner(a, b, *, px: int, py: int, kf: int, res, interpret: bool):
 def pdtrsm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh, lower: bool = True,
            unit_diag: bool = False, left: bool = True,
            block: Optional[int] = None, policy: Optional[str] = None,
-           use_kernel: Optional[bool] = None, interpret: bool = True,
+           use_kernel: Optional[bool] = None, interpret: Optional[bool] = None,
            registry=None) -> jnp.ndarray:
     """Solve op(T) X = B with the right-hand sides sharded over the mesh.
 
@@ -210,8 +210,8 @@ def pdtrsm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh, lower: bool = True,
                     block=block, policy=policy, use_kernel=use_kernel,
                     interpret=interpret, registry=registry)
 
-    f = shard_map(inner, mesh=mesh,
-                  in_specs=(P(None, None), P(None, ("x", "y"))),
-                  out_specs=P(None, ("x", "y")), check_rep=False)
+    f = jax.shard_map(inner, mesh=mesh,
+                      in_specs=(P(None, None), P(None, ("x", "y"))),
+                      out_specs=P(None, ("x", "y")), check_vma=False)
     x = f(a, rhs_p)[:, :nrhs]
     return x[:, 0] if vec else x

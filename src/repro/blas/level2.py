@@ -19,7 +19,7 @@ from repro.blas._deprecated import warn_once
 
 def gemv(a: jnp.ndarray, x: jnp.ndarray, beta=0.0, y=None,
          alpha=1.0, trans: bool = False, policy: Optional[str] = None,
-         use_kernel: Optional[bool] = None, interpret: bool = True,
+         use_kernel: Optional[bool] = None, interpret: Optional[bool] = None,
          registry=None) -> jnp.ndarray:
     """y <- alpha*op(A) x + beta*y (BLAS GEMV core).
 
@@ -117,7 +117,7 @@ def trsv(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
 
 def dgemv(a, x, beta=0.0, y=None, alpha=1.0, trans: bool = False,
           policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-          interpret: bool = True, registry=None,
+          interpret: Optional[bool] = None, registry=None,
           use_pallas: Optional[bool] = None):
     """Deprecated alias of :func:`repro.linalg.gemv` (old kwargs mapped to
     a per-call context). Warning + bitwise-identity oracle:

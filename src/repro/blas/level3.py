@@ -28,7 +28,7 @@ from repro.blas._deprecated import warn_once
 def gemm(a: jnp.ndarray, b: jnp.ndarray, c: Optional[jnp.ndarray] = None,
          alpha=1.0, beta=0.0, transa: bool = False, transb: bool = False,
          policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-         interpret: bool = True, registry=None) -> jnp.ndarray:
+         interpret: Optional[bool] = None, registry=None) -> jnp.ndarray:
     """C <- alpha * op(A) op(B) + beta * C (BLAS GEMM core).
 
     Parameters
@@ -44,7 +44,8 @@ def gemm(a: jnp.ndarray, b: jnp.ndarray, c: Optional[jnp.ndarray] = None,
         ``tuned`` = the registry's measured config, cold-starting to
         ``model``. ``use_kernel`` is the deprecated alias
         (True == "model", False == "reference").
-    interpret : run Pallas in interpret mode (required on CPU).
+    interpret : run Pallas in interpret mode; ``None`` takes the device's
+        mode (:func:`repro.kernels.ops.interpret_mode`).
 
     Returns
     -------
@@ -74,7 +75,8 @@ def gemm(a: jnp.ndarray, b: jnp.ndarray, c: Optional[jnp.ndarray] = None,
 def gemm_bias_act(a: jnp.ndarray, b: jnp.ndarray,
                   bias: Optional[jnp.ndarray] = None, epilogue: str = "none",
                   policy: Optional[str] = None,
-                  use_kernel: Optional[bool] = None, interpret: bool = True,
+                  use_kernel: Optional[bool] = None,
+                  interpret: Optional[bool] = None,
                   registry=None) -> jnp.ndarray:
     """C = act(A @ B + bias) - the fused-epilogue GEMM core.
 
@@ -107,7 +109,7 @@ def gemm_bias_act(a: jnp.ndarray, b: jnp.ndarray,
 def syrk(a: jnp.ndarray, c: Optional[jnp.ndarray] = None, alpha=1.0,
          beta=0.0, lower: bool = True, trans: bool = False,
          policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-         interpret: bool = True, registry=None) -> jnp.ndarray:
+         interpret: Optional[bool] = None, registry=None) -> jnp.ndarray:
     """C <- alpha op(A) op(A)^T + beta C (BLAS SYRK core), symmetric output.
 
     Parameters
@@ -153,7 +155,7 @@ def mirror_triangle(full: jnp.ndarray, lower: bool) -> jnp.ndarray:
 def trsm(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
          unit_diag: bool = False, left: bool = True,
          block: Optional[int] = None, policy: Optional[str] = None,
-         use_kernel: Optional[bool] = None, interpret: bool = True,
+         use_kernel: Optional[bool] = None, interpret: Optional[bool] = None,
          registry=None) -> jnp.ndarray:
     """Solve op(T) X = B (left=True) or X op(T) = B, T triangular, blocked.
 
@@ -243,7 +245,7 @@ def _trsm_unblocked(a: jnp.ndarray, b: jnp.ndarray, lower: bool,
 
 def dgemm(a, b, c=None, alpha=1.0, beta=0.0, transa: bool = False,
           transb: bool = False, policy: Optional[str] = None,
-          use_kernel: Optional[bool] = None, interpret: bool = True,
+          use_kernel: Optional[bool] = None, interpret: Optional[bool] = None,
           registry=None, use_pallas: Optional[bool] = None):
     """Deprecated alias of :func:`repro.linalg.gemm` (old kwargs mapped to
     a local, per-call context). Warning + bitwise-identity oracle:
@@ -259,7 +261,7 @@ def dgemm(a, b, c=None, alpha=1.0, beta=0.0, transa: bool = False,
 
 def dsyrk(a, c=None, alpha=1.0, beta=0.0, lower: bool = True,
           trans: bool = False, policy: Optional[str] = None,
-          use_kernel: Optional[bool] = None, interpret: bool = True,
+          use_kernel: Optional[bool] = None, interpret: Optional[bool] = None,
           registry=None, use_pallas: Optional[bool] = None):
     """Deprecated alias of :func:`repro.linalg.syrk`."""
     warn_once("dsyrk", "syrk")
@@ -274,7 +276,7 @@ def dsyrk(a, c=None, alpha=1.0, beta=0.0, lower: bool = True,
 def dtrsm(a, b, lower: bool = True, unit_diag: bool = False,
           left: bool = True, block: Optional[int] = None,
           policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-          interpret: bool = True, registry=None,
+          interpret: Optional[bool] = None, registry=None,
           use_pallas: Optional[bool] = None):
     """Deprecated alias of :func:`repro.linalg.trsm`."""
     warn_once("dtrsm", "trsm")

@@ -631,13 +631,23 @@ def plan_fused_chain(kind: str, m: int, n: int, k: int,
     unfused_t = solve_t + _stage_time(gemm_flops, unfused_gemm_b, mach) \
         + 2 * fill
     fused_t = solve_t + _stage_time(gemm_flops, fused_gemm_b, mach) + fill
-    # fused-kernel residency: L11 + AP + X (operand and accumulator-width
-    # copies, full n width - the solve cannot be column-tiled) plus one
-    # C/O row block (and the B row block for the lu form)
-    bm = min(g.bm, _round_up(m, max(mach.pe.sublane, 1)))
+    # fused-kernel residency: the pipeline double-buffers every block - L11,
+    # AP and the X output (full n width: the solve cannot be column-tiled),
+    # the C in/out row blocks and, for the lu form, B's - and the X scratch
+    # stays resident at accumulator width. The row block halves from the
+    # GEMM tile until that fits the machine's budget.
+    sub = max(mach.pe.sublane, 1)
     acc = _acc_bytes(db)
-    vmem = (k * k + k * n) * db + k * n * acc + k * n * db \
-        + bm * n * (db + acc) + (bm * k * db if form == "lu" else 0)
+
+    def residency(rows: int) -> int:
+        blocks = k * k + 2 * k * n + 2 * rows * n \
+            + (rows * k if form == "lu" else 0)
+        return 2 * blocks * db + k * n * acc
+
+    bm = min(g.bm, _round_up(m, sub))
+    while residency(bm) > budget and bm > sub:
+        bm = max(sub, _round_up(bm // 2, sub))
+    vmem = residency(bm)
     return FusedChainPlan(kind, form, g, bm, int(vmem), vmem <= budget,
                           int(solve_bytes + unfused_gemm_b),
                           int(solve_bytes + fused_gemm_b),

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro import obs as _obs
 from repro.obs import counters as _counters
@@ -223,9 +222,9 @@ def compressed_grad_sync(mesh: Mesh, axis_name: str = "pod"):
         return td.unflatten([o[0] for o in out]), td.unflatten([o[1] for o in out])
 
     spec = P()                                          # replicated leaves
-    return shard_map(sync, mesh=mesh,
-                     in_specs=(spec, spec), out_specs=(spec, spec),
-                     check_rep=False)
+    return jax.shard_map(sync, mesh=mesh,
+                         in_specs=(spec, spec), out_specs=(spec, spec),
+                         check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -276,6 +275,6 @@ def sharded_decode_attention(mesh: Mesh, dp_axes, kv_len_static: bool = False):
 
     qspec = P(dp if dp else None, None, None)
     kvspec = P(dp if dp else None, "model", None, None)
-    return shard_map(inner, mesh=mesh,
-                     in_specs=(qspec, kvspec, kvspec, P()),
-                     out_specs=qspec, check_rep=False)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(qspec, kvspec, kvspec, P()),
+                         out_specs=qspec, check_vma=False)

@@ -19,7 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_forward(stage_fn: Callable, mesh: Mesh,
@@ -66,8 +65,8 @@ def pipeline_forward(stage_fn: Callable, mesh: Mesh,
         return buf
 
     # P(stage_axis) is a pytree *prefix*: applies to every params leaf
-    return shard_map(inner, mesh=mesh, in_specs=(P(stage_axis), P()),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(inner, mesh=mesh, in_specs=(P(stage_axis), P()),
+                         out_specs=P(), check_vma=False)
 
 
 def stack_stage_params(per_stage_params):

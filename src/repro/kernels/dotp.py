@@ -24,7 +24,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.codesign import LANE, optimal_accumulators
-from repro.kernels.compat import CompilerParams
 
 
 def _dotp_kernel(x_ref, y_ref, o_ref, acc_ref, *, nsteps: int):
@@ -43,8 +42,10 @@ def _dotp_kernel(x_ref, y_ref, o_ref, acc_ref, *, nsteps: int):
 
 
 def dotp(x: jnp.ndarray, y: jnp.ndarray, accumulators: Optional[int] = None,
-         interpret: bool = True) -> jnp.ndarray:
-    """<x, y> with a U-accumulator streaming schedule; returns fp32 scalar."""
+         interpret: Optional[bool] = None) -> jnp.ndarray:
+    """<x, y> with a U-accumulator streaming schedule; returns fp32 scalar.
+    ``interpret`` ``None`` takes the device's mode
+    (:func:`repro.kernels.ops.interpret_mode`)."""
     assert x.shape == y.shape and x.ndim == 1
     n = x.shape[0]
     u = accumulators or optimal_accumulators(n)
@@ -54,6 +55,9 @@ def dotp(x: jnp.ndarray, y: jnp.ndarray, accumulators: Optional[int] = None,
         x = jnp.pad(x, (0, pad))
         y = jnp.pad(y, (0, pad))
     nsteps = (n + pad) // width
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode    # ops imports this
+        interpret = interpret_mode()
     xs = x.reshape(nsteps, u, LANE)
     ys = y.reshape(nsteps, u, LANE)
     partials = pl.pallas_call(
@@ -64,7 +68,7 @@ def dotp(x: jnp.ndarray, y: jnp.ndarray, accumulators: Optional[int] = None,
         out_specs=pl.BlockSpec((1, u, LANE), lambda i: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, u, LANE), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, u, LANE), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(xs, ys)

@@ -26,7 +26,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.codesign import LANE, plan_attention
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -84,8 +83,10 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               q_offset: int = 0, window: Optional[int] = None,
               kv_len: Optional[int] = None,
               block_q: Optional[int] = None, block_k: Optional[int] = None,
-              interpret: bool = True) -> jnp.ndarray:
-    """Flash attention; see module docstring for layout. Returns q-shaped."""
+              interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Flash attention; see module docstring for layout. Returns q-shaped.
+    ``interpret`` ``None`` takes the device's mode
+    (:func:`repro.kernels.ops.interpret_mode`)."""
     if 0 in q.shape or 0 in k.shape or 0 in v.shape:
         # zero-dim operands cannot tile a Pallas grid (rule KL004): an
         # empty batch/head/query/feature axis makes the output empty, and
@@ -108,6 +109,9 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pk_ - sk), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pk_ - sk), (0, 0)))
     nk = pk_ // bk
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode    # ops imports this
+        interpret = interpret_mode()
     out = pl.pallas_call(
         functools.partial(_attn_kernel, scale=scale, causal=causal,
                           q_offset=q_offset, kv_len=kv_len, window=window,
@@ -127,7 +131,7 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((bq, LANE), jnp.float32),   # running denom l
             pltpu.VMEM((bq, d), jnp.float32),      # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -37,8 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs as _obs
 from repro.core.codesign import GemmPlan, plan_fused_chain, plan_gemm
-from repro.kernels.compat import CompilerParams
-from repro.kernels.gemm import accumulator_dtype
+from repro.kernels.gemm import accumulator_dtype, mxu_precision, vmem_limit
 
 EPILOGUES = ("none", "relu", "gelu")
 
@@ -84,7 +83,8 @@ def _gemm_epilogue_kernel(*refs, nk: int, acc_dtype, epilogue: str,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=acc_dtype)
+                            preferred_element_type=acc_dtype,
+                            precision=mxu_precision(a_ref.dtype))
 
     @pl.when(k == nk - 1)
     def _flush():
@@ -96,7 +96,8 @@ def _gemm_epilogue_kernel(*refs, nk: int, acc_dtype, epilogue: str,
 def gemm_bias_act(a: jnp.ndarray, b: jnp.ndarray,
                   bias: Optional[jnp.ndarray] = None,
                   epilogue: str = "none", plan: Optional[GemmPlan] = None,
-                  out_dtype=None, interpret: bool = True) -> jnp.ndarray:
+                  out_dtype=None,
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """C = act(A @ B + bias) in one Pallas launch.
 
     Same grid/tiling contract as :func:`repro.kernels.gemm.gemm` (the
@@ -119,7 +120,12 @@ def gemm_bias_act(a: jnp.ndarray, b: jnp.ndarray,
     b_p = jnp.pad(b, ((0, pk - k), (0, pn - n))) if (pk, pn) != (k, n) else b
     nk = pk // bk
     acc_dtype = accumulator_dtype(a.dtype)
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode    # ops imports gemm
+        interpret = interpret_mode()
     has_bias = bias is not None
+    block_bytes = (bm * bk + bk * bn) * a.dtype.itemsize \
+        + bm * bn * jnp.dtype(out_dtype).itemsize
     in_specs = [
         pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
         pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
@@ -130,6 +136,7 @@ def gemm_bias_act(a: jnp.ndarray, b: jnp.ndarray,
                          ((0, 0), (0, pn - n)))
         in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
         operands.append(bias_p)
+        block_bytes += bn * bias_p.dtype.itemsize
     out = pl.pallas_call(
         functools.partial(_gemm_epilogue_kernel, nk=nk, acc_dtype=acc_dtype,
                           epilogue=epilogue, has_bias=has_bias),
@@ -138,8 +145,10 @@ def gemm_bias_act(a: jnp.ndarray, b: jnp.ndarray,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((pm, pn), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(
+                block_bytes, bm * bn * acc_dtype.itemsize)),
         interpret=interpret,
     )(*operands)
     return out[:m, :n]
@@ -154,6 +163,7 @@ def _trsm_gemm_kernel(*refs, form: str, unit_diag: bool, pnb: int,
     else:
         l_ref, ap_ref, c_ref, x_ref, o_ref, xs_ref = refs
     i = pl.program_id(0)
+    prec = mxu_precision(c_ref.dtype)
 
     @pl.when(i == 0)
     def _solve():
@@ -161,40 +171,43 @@ def _trsm_gemm_kernel(*refs, form: str, unit_diag: bool, pnb: int,
         # rows < r, extracted with one-hot reductions so the loop carries a
         # dense (pnb, n) accumulator - the serial divider chain of
         # level3._trsm_unblocked, run once in VMEM at accumulator width.
+        # Every value stays 2-D: Mosaic lowers no 1-D dot.
         l = l_ref[...].astype(acc_dtype)
         ap = ap_ref[...].astype(acc_dtype)
         ii = lax.broadcasted_iota(jnp.int32, l.shape, 0)
         jj = lax.broadcasted_iota(jnp.int32, l.shape, 1)
         strict = jnp.where(jj < ii, l, jnp.zeros_like(l))
-        dvec = jnp.sum(jnp.where(ii == jj, l, jnp.zeros_like(l)), axis=1)
         rows = lax.broadcasted_iota(jnp.int32, ap.shape, 0)
 
         def body(r, x):
             row_mask = (rows == r)                      # one-hot row of AP
             rhs = jnp.sum(jnp.where(row_mask, ap, jnp.zeros_like(ap)),
-                          axis=0)
-            lrow = jnp.sum(jnp.where(ii == r, strict,
-                                     jnp.zeros_like(strict)), axis=0)
-            s = rhs - lrow @ x
+                          axis=0, keepdims=True)        # (1, pn)
+            lrow = jnp.sum(jnp.where(ii == r, strict, jnp.zeros_like(strict)),
+                           axis=0, keepdims=True)       # (1, pnb)
+            s = rhs - jnp.dot(lrow, x, preferred_element_type=acc_dtype,
+                              precision=prec)
             if not unit_diag:
-                dk = jnp.sum(jnp.where(jnp.arange(pnb) == r, dvec,
-                                       jnp.zeros_like(dvec)))
+                dk = jnp.sum(jnp.where((ii == r) & (jj == r), l,
+                                       jnp.zeros_like(l)),
+                             axis=(0, 1), keepdims=True)  # (1, 1)
                 s = s / dk
-            return x + row_mask.astype(acc_dtype) * s[None, :]
+            return x + row_mask.astype(acc_dtype) * s
 
         x = lax.fori_loop(0, pnb, body, jnp.zeros(ap.shape, acc_dtype))
         xs_ref[...] = x
         x_ref[...] = x.astype(x_ref.dtype)
 
-    x = xs_ref[...]
     if form == "lu":
-        upd = jnp.dot(bl_ref[...].astype(acc_dtype), x,
-                      preferred_element_type=acc_dtype)
+        upd = jnp.dot(bl_ref[...].astype(acc_dtype), xs_ref[...],
+                      preferred_element_type=acc_dtype, precision=prec)
     else:
-        # index dtypes must match even under x64 (program_id is int32)
-        col0 = (i * bm).astype(jnp.int32)
-        xi = lax.dynamic_slice(x, (jnp.int32(0), col0), (pnb, bm))
-        upd = jnp.dot(xi.T, x, preferred_element_type=acc_dtype)
+        # this step's column block of X, read from the scratch ref
+        col0 = pl.multiple_of(i * bm, bm)
+        xi = xs_ref[:, pl.ds(col0, bm)]
+        upd = lax.dot_general(xi, xs_ref[...], (((0,), (0,)), ((), ())),
+                              preferred_element_type=acc_dtype,
+                              precision=prec)
     o_ref[...] = (c_ref[...].astype(acc_dtype) - upd).astype(o_ref.dtype)
 
 
@@ -202,7 +215,8 @@ def trsm_gemm(l11: jnp.ndarray, a_panel: jnp.ndarray,
               b_left: Optional[jnp.ndarray], c: jnp.ndarray,
               form: str = "lu", unit_diag: bool = False,
               row_block: Optional[int] = None,
-              interpret: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+              interpret: Optional[bool] = None
+              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Fused X = L11^{-1} AP then C -= (B X | X^T X), one Pallas launch.
 
     Parameters
@@ -241,6 +255,9 @@ def trsm_gemm(l11: jnp.ndarray, a_panel: jnp.ndarray,
         assert b_left is not None and b_left.shape == (m, nb)
     dtype = c.dtype
     acc_dtype = accumulator_dtype(dtype)
+    if interpret is None:
+        from repro.kernels.ops import interpret_mode    # ops imports gemm
+        interpret = interpret_mode()
     pnb = -(-nb // 8) * 8
     pn = -(-n // 128) * 128
     if form == "syrk":
@@ -267,6 +284,10 @@ def trsm_gemm(l11: jnp.ndarray, a_panel: jnp.ndarray,
         operands.append(jnp.pad(b_left, ((0, pm - m), (0, pnb - nb))))
     in_specs.append(pl.BlockSpec((bm, pn), lambda i: (i, 0)))
     operands.append(c_p)
+    db = jnp.dtype(dtype).itemsize
+    # L11, AP, the X output and the C in/out row blocks (+ B's for "lu")
+    block_bytes = (pnb * pnb + 2 * pnb * pn + 2 * bm * pn
+                   + (bm * pnb if form == "lu" else 0)) * db
     x_out, c_out = pl.pallas_call(
         functools.partial(_trsm_gemm_kernel, form=form, unit_diag=unit_diag,
                           pnb=pnb, bm=bm, acc_dtype=acc_dtype),
@@ -281,7 +302,10 @@ def trsm_gemm(l11: jnp.ndarray, a_panel: jnp.ndarray,
             jax.ShapeDtypeStruct((pm, pn), dtype),
         ],
         scratch_shapes=[pltpu.VMEM((pnb, pn), acc_dtype)],
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit(
+                block_bytes, pnb * pn * acc_dtype.itemsize)),
         interpret=interpret,
     )(*operands)
     return x_out[:nb, :n], c_out[:m, :n]

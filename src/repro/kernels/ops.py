@@ -24,13 +24,22 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run in interpret mode: exactly when the
+    default backend is not a TPU. Every ``interpret=None`` default in the
+    kernels, the BLAS/LAPACK drivers and :mod:`repro.linalg`'s context
+    resolves here, so a TPU run always compiles its kernels and a CPU run
+    always interprets them."""
+    return not _on_tpu()
+
+
 def gemm(a, b, plan=None, use_pallas: Optional[bool] = None,
          interpret: Optional[bool] = None):
     use = _on_tpu() if use_pallas is None else use_pallas
     if not use:
         return ref.gemm(a, b)
     return _gemm.gemm(a, b, plan=plan,
-                      interpret=not _on_tpu() if interpret is None else interpret)
+                      interpret=interpret)
 
 
 def dotp(x, y, accumulators=None, use_pallas: Optional[bool] = None,
@@ -39,7 +48,7 @@ def dotp(x, y, accumulators=None, use_pallas: Optional[bool] = None,
     if not use:
         return ref.dotp(x, y)
     return _dotp.dotp(x, y, accumulators=accumulators,
-                      interpret=not _on_tpu() if interpret is None else interpret)
+                      interpret=interpret)
 
 
 BLOCKED_ATTN_THRESHOLD = 2048
@@ -63,7 +72,7 @@ def attention(q, k, v, causal: bool = True, scale=None, q_offset: int = 0,
                              q_offset=q_offset, window=window)
     return _fa.attention(q, k, v, causal=causal, scale=scale,
                          q_offset=q_offset, window=window, kv_len=kv_len,
-                         interpret=not _on_tpu() if interpret is None else interpret,
+                         interpret=interpret,
                          **block_kw)
 
 
@@ -79,5 +88,5 @@ def ssd(x, a_log, B, C, chunk=None, use_pallas: Optional[bool] = None,
     Bt = jnp.moveaxis(B, 2, 1)
     Ct = jnp.moveaxis(C, 2, 1)
     y = _ssd.ssd_scan(xt, at, Bt, Ct, chunk=chunk,
-                      interpret=not _on_tpu() if interpret is None else interpret)
+                      interpret=interpret)
     return jnp.moveaxis(y, 1, 2)

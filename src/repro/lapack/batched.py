@@ -63,7 +63,7 @@ def _resolve_block(kmax: int, block: Optional[int], kind: str,
 def batched_potrf(a: jnp.ndarray, block: Optional[int] = None,
                   policy: Optional[str] = None,
                   use_kernel: Optional[bool] = None,
-                  interpret: bool = True,
+                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """Cholesky of a (B, n, n) SPD batch; factors holds L (lower).
 
@@ -86,7 +86,7 @@ def batched_potrf(a: jnp.ndarray, block: Optional[int] = None,
 def batched_getrf(a: jnp.ndarray, block: Optional[int] = None,
                   policy: Optional[str] = None,
                   use_kernel: Optional[bool] = None,
-                  interpret: bool = True,
+                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """LU with partial pivoting of a (B, m, n) batch.
 
@@ -108,7 +108,7 @@ def batched_getrf(a: jnp.ndarray, block: Optional[int] = None,
 def batched_geqrf(a: jnp.ndarray, block: Optional[int] = None,
                   policy: Optional[str] = None,
                   use_kernel: Optional[bool] = None,
-                  interpret: bool = True,
+                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """Householder QR of a (B, m, n) batch (packed R/V + tau per item).
 
@@ -128,7 +128,8 @@ def batched_geqrf(a: jnp.ndarray, block: Optional[int] = None,
 def batched_solve(res: FactorizationResult, b: jnp.ndarray,
                   policy: Optional[str] = None,
                   use_kernel: Optional[bool] = None,
-                  interpret: bool = True, registry=None) -> jnp.ndarray:
+                  interpret: Optional[bool] = None,
+                  registry=None) -> jnp.ndarray:
     """Solve A_i x_i = b_i for every batch item from a FactorizationResult.
 
     b: (B, n) or (B, n, k). potrf solves the SPD system L L^T x = b; getrf

@@ -67,7 +67,7 @@ def potrf_unblocked(a: jnp.ndarray) -> jnp.ndarray:
 
 def potrf(a: jnp.ndarray, block: Optional[int] = None,
           policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-          interpret: bool = True, registry=None,
+          interpret: Optional[bool] = None, registry=None,
           fuse: Optional[bool] = None) -> jnp.ndarray:
     """Blocked right-looking POTRF: panel = hazards, trailing = GEMM.
 

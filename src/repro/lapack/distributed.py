@@ -22,7 +22,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.distributed.collectives import CollectiveRecord, emit_record
 from repro.lapack import batched as _batched
@@ -62,15 +61,15 @@ def _shard_batched(mesh: Mesh, fn, a: jnp.ndarray, n_out: int):
     batch-sharded ``a``; returns the tuple with the pad still attached."""
     axes = _mesh_axes(mesh)
     spec = P(axes)                              # batch axis only; rest open
-    return shard_map(fn, mesh=mesh, in_specs=(spec,),
-                     out_specs=tuple(spec for _ in range(n_out)),
-                     check_rep=False)(a)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,),
+                         out_specs=tuple(spec for _ in range(n_out)),
+                         check_vma=False)(a)
 
 
 def batched_potrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
                   policy: Optional[str] = None,
                   use_kernel: Optional[bool] = None,
-                  interpret: bool = True,
+                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """Cholesky of a (B, n, n) SPD batch, batch-sharded over ``mesh``.
 
@@ -112,7 +111,7 @@ def batched_potrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
 def batched_getrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
                   policy: Optional[str] = None,
                   use_kernel: Optional[bool] = None,
-                  interpret: bool = True,
+                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """LU with partial pivoting of a (B, m, n) batch, batch-sharded.
 
@@ -138,7 +137,7 @@ def batched_getrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
 def batched_geqrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
                   policy: Optional[str] = None,
                   use_kernel: Optional[bool] = None,
-                  interpret: bool = True,
+                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """Householder QR of a (B, m, n) batch, batch-sharded.
 
@@ -162,7 +161,8 @@ def batched_geqrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
 def batched_solve(res: FactorizationResult, b: jnp.ndarray, mesh: Mesh,
                   policy: Optional[str] = None,
                   use_kernel: Optional[bool] = None,
-                  interpret: bool = True, registry=None) -> jnp.ndarray:
+                  interpret: Optional[bool] = None,
+                  registry=None) -> jnp.ndarray:
     """Solve A_i x_i = b_i for a batch-sharded FactorizationResult.
 
     ``res`` is a result of any driver in this module (or the single-device
@@ -225,7 +225,7 @@ def batched_solve(res: FactorizationResult, b: jnp.ndarray, mesh: Mesh,
         return _batched.batched_solve(lres, r, policy=pol,
                                       interpret=interpret, registry=registry)
 
-    x = shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
-                  out_specs=spec, check_rep=False)(*operands)
+    x = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
+                      out_specs=spec, check_vma=False)(*operands)
     x = x[:b0]
     return x[:, :, 0] if vec else x

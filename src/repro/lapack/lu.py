@@ -61,7 +61,7 @@ def getrf_unblocked(a: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 def getrf(a: jnp.ndarray, block: Optional[int] = None,
           policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-          interpret: bool = True, registry=None,
+          interpret: Optional[bool] = None, registry=None,
           fuse: Optional[bool] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Blocked right-looking LU with partial pivoting (LAPACK DGETRF).
 

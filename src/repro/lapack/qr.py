@@ -102,7 +102,7 @@ def _larft(v: jnp.ndarray, tau: jnp.ndarray) -> jnp.ndarray:
 
 def geqrf(a: jnp.ndarray, block: Optional[int] = None,
           policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-          interpret: bool = True,
+          interpret: Optional[bool] = None,
           registry=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Blocked Householder QR, compact WY (LAPACK DGEQRF).
 
@@ -207,7 +207,7 @@ def q_from_geqrf(packed: jnp.ndarray, tau: jnp.ndarray) -> jnp.ndarray:
 
 def qr(a: jnp.ndarray, block: Optional[int] = None,
        policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-       interpret: bool = True,
+       interpret: Optional[bool] = None,
        registry=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Convenience thin-QR: returns (Q (m, min(m,n)), R (min(m,n), n))
     from :func:`geqrf` + :func:`q_from_geqrf`; same

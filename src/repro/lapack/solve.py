@@ -18,7 +18,7 @@ from repro.lapack.qr import geqrf, q_from_geqrf
 
 def gesv(a: jnp.ndarray, b: jnp.ndarray, block: Optional[int] = None,
          policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-         interpret: bool = True, registry=None) -> jnp.ndarray:
+         interpret: Optional[bool] = None, registry=None) -> jnp.ndarray:
     """Solve A X = B via LU with partial pivoting (LAPACK DGESV).
 
     Parameters
@@ -53,7 +53,7 @@ def gesv(a: jnp.ndarray, b: jnp.ndarray, block: Optional[int] = None,
 
 def lstsq_qr(a: jnp.ndarray, b: jnp.ndarray, block: Optional[int] = None,
              policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-             interpret: bool = True, registry=None) -> jnp.ndarray:
+             interpret: Optional[bool] = None, registry=None) -> jnp.ndarray:
     """Least-squares min ||A x - b|| via QR: x = R^{-1} Q^T b.
 
     Parameters

@@ -1,11 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any other import (jax locks the device
-count at first init); 512 placeholder CPU devices let ``make_production_mesh``
-build the production meshes. For each cell this driver:
+``main`` forces 512 placeholder CPU devices before JAX initializes its
+backend (the device count is locked at first use), so that
+``make_production_mesh`` can build the production meshes; importing this
+module changes nothing. For each cell this driver:
 
   1. builds the abstract inputs (ShapeDtypeStructs - zero allocation),
   2. jits the right step (train_step / prefill / serve_step) with the
@@ -21,6 +19,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -144,6 +143,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, accum=None,
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=registry.ARCHS)
     ap.add_argument("--shape", choices=[s.name for s in registry.SHAPES])

@@ -7,17 +7,10 @@ Single pod: 16 x 16 = 256 chips ("data", "model"); multi-pod: 2 x 16 x 16 =
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are implicitly Auto on every axis
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

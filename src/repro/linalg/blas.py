@@ -58,10 +58,20 @@ def _routine(op, info=None):
     the wrapper takes a dict-free early return into the numeric body:
     untraced calls execute byte-for-byte the pre-obs path. An annotation
     failure never breaks the call (``info`` runs under ``except``).
+
+    The body traces under ``jax.default_matmul_precision("highest")``: a
+    float32 routine multiplies in float32. XLA on a TPU otherwise rounds
+    float32 matmul operands to one bfloat16 pass, which no LAPACK-style
+    residual survives; bfloat16 operands are exact either way, and CPU
+    numerics are unchanged.
     """
     def deco(fn):
         @functools.wraps(fn)
         def wrapper(*args, context=None, **kw):
+            with jax.default_matmul_precision("highest"):
+                return body(*args, context=context, **kw)
+
+        def body(*args, context=None, **kw):
             ctx = current(context)
             mach = resolved_machine(ctx)
             tr = resolved_obs(ctx)

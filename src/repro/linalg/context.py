@@ -22,7 +22,9 @@ The context carries the *deployment shape* of a call:
     computation and the result is cast back to the storage dtype. ``None``
     (the default) leaves numerics exactly as the operand dtype dictates.
 ``interpret``
-    Run Pallas kernels in interpret mode (required on CPU; default True).
+    Run Pallas kernels in interpret mode. ``None`` (the default) takes it
+    from the device (:func:`repro.kernels.ops.interpret_mode`): interpret
+    exactly when the backend is not a TPU.
 ``machine``
     A :class:`repro.arch.MachineSpec` (or registered machine name) the
     call's planners and tuner lookups resolve against; ``None`` (the
@@ -145,7 +147,7 @@ class ExecutionContext:
             reg_path = getattr(reg, "path", None)
         acc = None if self.accum_dtype in (UNSET, None) \
             else np.dtype(self.accum_dtype).name
-        interp = True if self.interpret is UNSET else bool(self.interpret)
+        interp = resolved_interpret(self)
         from repro import arch as _arch
         if self.machine in (UNSET, None):
             mach = _arch.current_machine().name
@@ -166,7 +168,7 @@ class ExecutionContext:
 
 # fully-resolved root: what a call sees with no context set anywhere
 _DEFAULT = ExecutionContext(policy=None, mesh=None, registry=None,
-                            accum_dtype=None, interpret=True, machine=None,
+                            accum_dtype=None, interpret=None, machine=None,
                             obs=None)
 # process-global base (set_context) + per-thread/task overlay scopes (use)
 _base = _DEFAULT
@@ -241,8 +243,9 @@ def reset_context() -> None:
     _scopes.set(())
 
 
-def compat_context(policy=None, use_kernel=None, interpret: bool = True,
-                   registry=None, use_pallas=None) -> ExecutionContext:
+def compat_context(policy=None, use_kernel=None,
+                   interpret: Optional[bool] = None, registry=None,
+                   use_pallas=None) -> ExecutionContext:
     """Old kwarg triple -> per-call context (the d-prefixed shims' bridge).
 
     Pins ``mesh=None``, ``accum_dtype=None``, and ``machine=None`` so a
@@ -304,7 +307,11 @@ def resolved_policy(ctx: ExecutionContext):
 
 
 def resolved_interpret(ctx: ExecutionContext) -> bool:
-    return True if ctx.interpret is UNSET else bool(ctx.interpret)
+    """ctx.interpret as a bool; ``UNSET``/``None`` take the device's mode."""
+    if ctx.interpret is UNSET or ctx.interpret is None:
+        from repro.kernels.ops import interpret_mode
+        return interpret_mode()
+    return bool(ctx.interpret)
 
 
 def resolved_accum_dtype(ctx: ExecutionContext):

@@ -14,6 +14,9 @@ Resolution table (``source`` records which row fired):
     model       (never consulted)   plan_gemm / plan_trsm config
     tuned       stored config       model config  (source="fallback-model")
 
+On a TPU, float64 under ``model``/``tuned`` resolves to XLA instead
+(``source="xla-float64"``): Mosaic compiles no float64 kernel.
+
 The miss column is why a cold-start ``tuned`` run is numerically identical
 to ``model``: both execute the same kernel with the same plan.
 """
@@ -55,7 +58,6 @@ DISPATCHED_MODULES = (
     "repro/core/",
     "repro/kernels/ops.py", "repro/kernels/ref.py", "repro/kernels/gemm.py",
     "repro/kernels/fused.py", "repro/kernels/dotp.py",
-    "repro/kernels/compat.py",
 )
 
 
@@ -66,7 +68,7 @@ class Resolution:
     op: str
     policy: str                   # "reference" | "model" | "tuned"
     source: str                   # "reference" | "model" | "registry" |
-                                  # "fallback-model"
+                                  # "fallback-model" | "xla-float64"
     use_pallas: bool
     gemm_plan: Optional[GemmPlan] = None
     block: Optional[int] = None   # trsm diagonal width
@@ -174,6 +176,12 @@ def resolve(op: str, shape: Tuple[int, ...], dtype,
                           machine=mach.name))
     dtype = jnp.dtype(dtype)
     backend = backend or jax.default_backend()
+    if dtype == jnp.float64 and backend == "tpu":
+        # Mosaic has no float64, so no Pallas kernel compiles for it: the
+        # kernel policies hand float64 to XLA, and the provenance says so
+        return _observed(Resolution(op, pol, "xla-float64", False,
+                                    block=64 if op == "trsm" else None,
+                                    mesh=mesh_str, machine=mach.name))
     cfg = None
     source = "model"
     if pol == "tuned":
@@ -252,7 +260,7 @@ def resolve(op: str, shape: Tuple[int, ...], dtype,
     return _observed(Resolution(op, pol, source, True, block=block, machine=mach.name))
 
 
-def _gemm_exec(a, b, res: Resolution, interpret: bool):
+def _gemm_exec(a, b, res: Resolution, interpret: Optional[bool]):
     if not res.use_pallas or 0 in a.shape or 0 in b.shape:
         # degenerate operands (e.g. a wide-LU trailing block with no rows
         # left) cannot tile a Pallas grid; plain jnp handles empties
@@ -267,7 +275,8 @@ def _gemm_exec(a, b, res: Resolution, interpret: bool):
 
 
 def dispatch(op: str, *args, policy: Optional[str] = None,
-             use_kernel: Optional[bool] = None, interpret: bool = True,
+             use_kernel: Optional[bool] = None,
+             interpret: Optional[bool] = None,
              registry: Optional[Registry] = None,
              machine: Optional[MachineSpec] = None, **kw):
     """One entry point for every BLAS-3 / blocked-LAPACK kernel call.

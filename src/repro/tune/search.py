@@ -140,7 +140,6 @@ def tune_gemm(m: int, n: int, k: int, dtype=jnp.float32,
     mach = _mach(machine)
     reg = registry if registry is not None else default_registry()
     backend = jax.default_backend()
-    interp = (backend != "tpu") if interpret is None else interpret
     dtype = jnp.dtype(dtype)
     model_pick = plan_gemm(m, n, k, dtype_bytes=dtype.itemsize, machine=mach)
     cands = gemm_candidates(m, n, k, dtype_bytes=dtype.itemsize,
@@ -152,7 +151,7 @@ def tune_gemm(m: int, n: int, k: int, dtype=jnp.float32,
     best_i, best_t = 0, None
     for i, plan in enumerate(cands):
         f = jax.jit(lambda x, y, p=plan: ops.gemm(
-            x, y, plan=p, use_pallas=True, interpret=interp))
+            x, y, plan=p, use_pallas=True, interpret=interpret))
         ms = _measure.measure(f, a, b, min_reps=reps, max_reps=2 * reps)
         t = ms.seconds_median
         model_s = model_score(plan, m, n, k, dtype.itemsize, machine=mach)
@@ -193,7 +192,6 @@ def tune_fused_gemm(m: int, n: int, k: int, epilogue: str = "relu",
     mach = _mach(machine)
     reg = registry if registry is not None else default_registry()
     backend = jax.default_backend()
-    interp = (backend != "tpu") if interpret is None else interpret
     dtype = jnp.dtype(dtype)
     chain = plan_fused_chain("gemm+epilogue", m, n, k,
                              dtype_bytes=dtype.itemsize, epilogue=epilogue,
@@ -206,12 +204,12 @@ def tune_fused_gemm(m: int, n: int, k: int, epilogue: str = "relu",
             if has_bias else None)
 
     def staged(x, y, bb):
-        c = ops.gemm(x, y, plan=plan, use_pallas=True, interpret=interp)
+        c = ops.gemm(x, y, plan=plan, use_pallas=True, interpret=interpret)
         return _fk.apply_epilogue(c, epilogue, bb)
 
     def fused_fn(x, y, bb):
         return _fk.gemm_bias_act(x, y, bias=bb, epilogue=epilogue,
-                                 plan=plan, interpret=interp)
+                                 plan=plan, interpret=interpret)
 
     measured = []
     best_i, best_t = 0, None

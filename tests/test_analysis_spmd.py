@@ -280,7 +280,6 @@ _PRELUDE = """
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro import analysis
 from repro.analysis import report, spmd_lint
 from repro.blas import distributed as dblas
@@ -321,8 +320,8 @@ def test_distributed_sweep_is_clean_and_seeded_violations_fire():
     def two_cycles(x):
         perm = [(0, 1), (1, 0), (2, 3), (3, 2)]
         return jax.lax.ppermute(x, "x", perm)
-    bad = shard_map(two_cycles, mesh=mesh, in_specs=P("x", None),
-                    out_specs=P("x", None), check_rep=False)
+    bad = jax.shard_map(two_cycles, mesh=mesh, in_specs=P("x", None),
+                        out_specs=P("x", None), check_vma=False)
     closed = jax.make_jaxpr(bad)(jnp.ones((8, 4), jnp.float32))
     f = spmd_lint.lint_collective_jaxpr(closed)
     assert {x.rule for x in f} == {"CC001"}, f

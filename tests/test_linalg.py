@@ -14,6 +14,7 @@ import os
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax.numpy as jnp
 import numpy as np
@@ -198,6 +199,36 @@ def test_context_describe_is_jsonable():
     json.dumps(d)
     # defaults resolve to the process default policy
     assert linalg.get_context().describe()["policy"] == "reference"
+
+
+def test_default_context_interpret_comes_from_the_device(monkeypatch):
+    """The root context leaves interpret mode to the device: True on the
+    CPU backend, False on a TPU, and describe() reports the resolved
+    value. An explicit interpret still wins."""
+    from repro.kernels import ops
+    from repro.linalg.context import resolved_interpret
+    ctx = linalg.get_context()
+    assert ctx.interpret is None                         # not pinned
+    assert resolved_interpret(ctx) is True               # CPU backend
+    assert ctx.describe()["interpret"] is True
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    assert resolved_interpret(ctx) is False
+    assert ctx.describe()["interpret"] is False
+    with linalg.use(interpret=True):
+        assert linalg.get_context().describe()["interpret"] is True
+
+
+def test_compile_cache_dir_is_fixed(monkeypatch):
+    """The compile cache lives where JAX_COMPILATION_CACHE_DIR says, else
+    at one path inside the checkout - the same on every call."""
+    from repro.runtime import compile_cache as cc
+    monkeypatch.setenv(cc.ENV, "/some/shared/cache")
+    assert cc.compile_cache_dir() == "/some/shared/cache"
+    monkeypatch.delenv(cc.ENV)
+    first = cc.compile_cache_dir()
+    assert first == cc.compile_cache_dir()
+    root = Path(__file__).resolve().parents[1]
+    assert Path(first) == root / ".jax_cache"
 
 
 def test_context_registry_path_reaches_dispatch(rng, assert_close, tmp_path):

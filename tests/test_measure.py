@@ -167,6 +167,19 @@ def test_calibrate_rejects_foreign_backend():
         arch.calibrate(backend="tpu", **FAST_CAL)
 
 
+@pytest.mark.parametrize("kind,base", [("cpu", "cpu-host"),
+                                       ("TPU v5 lite", "tpu-like"),
+                                       ("TPU v4", None)])
+def test_calibration_base_follows_device_kind(kind, base):
+    """The base spec comes from the device kind; a device with no entry
+    is refused rather than priced as some other chip."""
+    from repro.arch.calibrate import BASE_BY_DEVICE_KIND, fit_machine
+    assert BASE_BY_DEVICE_KIND.get(kind) == base
+    if base is None:
+        with pytest.raises(ValueError, match="device kind"):
+            fit_machine({"backend": "tpu", "device_kind": kind})
+
+
 # ----------------------- bench rows carry the fields ------------------------
 
 def test_fast_bench_rows_have_measurement_fields(tmp_path):

@@ -122,6 +122,28 @@ def test_cold_start_tuned_identical_to_use_kernel_path(rng, tmp_path):
 
 # ----------------------------- policy resolution ----------------------------
 
+@pytest.mark.parametrize("op,shape", [("gemm", (64, 64, 64)),
+                                      ("trsm", (64, 8)),
+                                      ("trsm+gemm", (64, 64, 16)),
+                                      ("pdgemm", (64, 64, 64))])
+@pytest.mark.parametrize("pol", ["model", "tuned"])
+def test_float64_on_tpu_resolves_to_xla(op, shape, pol):
+    """Mosaic compiles no float64 kernel, so on a TPU the kernel policies
+    hand float64 to XLA and say why; float32 still reaches the kernel."""
+    mesh = (2, 2) if op == "pdgemm" else None
+    res = dispatch.resolve(op, shape, jnp.float64, policy=pol,
+                           backend="tpu", mesh=mesh)
+    assert (res.source, res.use_pallas, res.fused) == \
+        ("xla-float64", False, False)
+    assert res.describe()["source"] == "xla-float64"
+    f32 = dispatch.resolve(op, shape, jnp.float32, policy=pol,
+                           backend="tpu", mesh=mesh)
+    assert f32.use_pallas and f32.source != "xla-float64"
+    cpu = dispatch.resolve(op, shape, jnp.float64, policy=pol,
+                           backend="cpu", mesh=mesh)
+    assert cpu.use_pallas                    # interpret mode handles f64
+
+
 def test_use_kernel_alias_mapping():
     assert policy.resolve_policy("tuned", use_kernel=False) == "tuned"
     assert policy.resolve_policy(None, use_kernel=True) == "model"

@@ -315,9 +315,10 @@ def check_obs(errors) -> None:
     # the disabled-path contract: no ambient trace -> the shared no-op span
     if obs.enabled():
         errors.append("obs.enabled() is True at import with no trace active")
-    if obs.span("surface-check") is not obs.NOOP_SPAN:
-        errors.append("obs.span() off-trace must return the NOOP_SPAN "
-                      "singleton (dict-free disabled path)")
+    with obs.span("surface-check") as sp:
+        if sp is not obs.NOOP_SPAN:
+            errors.append("obs.span() off-trace must enter as the NOOP_SPAN "
+                          "singleton (dict-free disabled path)")
 
 
 def check_analysis(errors) -> None:

@@ -118,9 +118,10 @@ def getrf(a: jnp.ndarray, block: Optional[int] = None,
             k = j0 + kk
             col = jnp.where(rows >= k, jnp.abs(A[:, k]), -jnp.inf)
             p = jnp.argmax(col).astype(jnp.int32)   # ipiv stays int32 (x64)
-            piv = piv.at[kk].set(p)
-            rk, rp = A[k], A[p]
-            A = A.at[k].set(rp).at[p].set(rk)
+            with _obs.span("getrf.swap", cat="swap"):
+                piv = piv.at[kk].set(p)
+                rk, rp = A[k], A[p]
+                A = A.at[k].set(rp).at[p].set(rk)
             pivval = A[k, k]
             safe = jnp.where(jnp.abs(pivval) > 0, pivval, 1.0)
             l = jnp.where(rows > k, A[:, k] / safe, 0.0)

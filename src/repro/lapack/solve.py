@@ -11,6 +11,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from repro import obs as _obs
 from repro.blas.level3 import trsm
 from repro.lapack.lu import apply_ipiv, getrf
 from repro.lapack.qr import geqrf, q_from_geqrf
@@ -43,11 +44,12 @@ def gesv(a: jnp.ndarray, b: jnp.ndarray, block: Optional[int] = None,
     packed, piv = getrf(a, block=block, policy=pol, interpret=interpret,
                         registry=registry)
     rhs = b if b.ndim == 2 else b[:, None]
-    rhs = apply_ipiv(rhs, piv)
-    y = trsm(packed, rhs, lower=True, unit_diag=True, left=True,
-             policy=pol, interpret=interpret, registry=registry)
-    x = trsm(packed, y, lower=False, unit_diag=False, left=True,
-             policy=pol, interpret=interpret, registry=registry)
+    with _obs.span("gesv.getrs", cat="solve"):
+        rhs = apply_ipiv(rhs, piv)
+        y = trsm(packed, rhs, lower=True, unit_diag=True, left=True,
+                 policy=pol, interpret=interpret, registry=registry)
+        x = trsm(packed, y, lower=False, unit_diag=False, left=True,
+                 policy=pol, interpret=interpret, registry=registry)
     return x if b.ndim == 2 else x[:, 0]
 
 

@@ -50,13 +50,15 @@ def _routine(op, info=None):
     machine inherits whatever scope (or the process default) is already
     active.
 
-    When a trace is capturing (the ambient :func:`repro.obs.trace` scope,
-    or an explicit ``ctx.obs``), the body runs under a
-    ``linalg.<op>`` span annotated by ``info(*args, **kw)`` - shapes,
-    dtype, flop/byte counts - which the span prices against the ambient
-    machine at close (``docs/observability.md``). With no capture active
-    the wrapper takes a dict-free early return into the numeric body:
-    untraced calls execute byte-for-byte the pre-obs path. An annotation
+    The body always runs under the ``linalg.<op>`` span, so every
+    operation of the call carries the routine's name in the compiled
+    program and the call shows on the profiler's host timeline. When a
+    trace is capturing (the ambient :func:`repro.obs.trace` scope, or an
+    explicit ``ctx.obs``), the span is captured and annotated by
+    ``info(*args, **kw)`` - shapes, dtype, flop/byte counts - which the
+    span prices against the ambient machine at close
+    (``docs/observability.md``). With no capture active the wrapper
+    takes a dict-free early return into the numeric body. An annotation
     failure never breaks the call (``info`` runs under ``except``).
 
     The body traces under ``jax.default_matmul_precision("highest")``: a
@@ -76,11 +78,12 @@ def _routine(op, info=None):
             mach = resolved_machine(ctx)
             tr = resolved_obs(ctx)
             if tr is None and not _obs.enabled():
-                # fast path: no capture anywhere - identical to pre-obs
-                if mach is None:
-                    return fn(*args, context=ctx, **kw)
-                with _arch.machine_scope(mach):
-                    return fn(*args, context=ctx, **kw)
+                # fast path: no capture anywhere
+                with _obs.span("linalg." + op):
+                    if mach is None:
+                        return fn(*args, context=ctx, **kw)
+                    with _arch.machine_scope(mach):
+                        return fn(*args, context=ctx, **kw)
             with contextlib.ExitStack() as st:
                 if mach is not None:
                     st.enter_context(_arch.machine_scope(mach))
@@ -88,12 +91,11 @@ def _routine(op, info=None):
                     # ctx.obs=False under an ambient trace: mask capture
                     # for the whole body (nested spans included)
                     st.enter_context(_obs.capture(None))
-                    return fn(*args, context=ctx, **kw)
-                if tr is not _obs.current_trace():
+                elif tr is not _obs.current_trace():
                     st.enter_context(_obs.capture(tr))
                 sp = st.enter_context(_obs.span("linalg." + op,
                                                 cat="routine"))
-                if info is not None:
+                if tr is not None and info is not None:
                     try:
                         sp.annotate(**info(*args, **kw))
                     except Exception:

@@ -7,12 +7,18 @@ The capture scope mirrors how :func:`repro.linalg.use` and
 own capture. The cardinal rule is that observation never changes
 numerics or, when disabled, costs anything measurable:
 
+* **Always**: a span names its extent twice, with or without a capture:
+  ``jax.named_scope(name)`` writes the name into the ``op_name``
+  metadata of every operation traced inside it (under ``jax.jit`` that
+  costs nothing at run time; the compiled program is the same), and
+  ``jax.profiler.TraceAnnotation(name)`` puts the extent on the
+  profiler's host timeline. A device trace can then be split by span.
 * **Disabled path**: :func:`span` checks one contextvar and returns a
-  shared no-op singleton - no ``Span`` object, no attrs dict retained, no
-  timestamps taken. The :mod:`repro.linalg` routine wrappers go further
-  and skip the :func:`span` call entirely (a dict-free early return into
-  the numeric body), so an untraced call is byte-for-byte the pre-obs
-  code path.
+  :class:`_Scope` that holds just those two context managers, and whose
+  ``with`` target is the shared no-op :data:`NOOP_SPAN` - no ``Span``
+  object, no attrs dict retained, no timestamps taken. The
+  :mod:`repro.linalg` routine wrappers take a dict-free early return
+  into the numeric body under that scope.
 * **Enabled path**: a :class:`Span` records wall time
   (``time.perf_counter`` relative to the trace epoch), name/category,
   whatever the instrumentation :meth:`Span.annotate`\\ s (shapes, dtype,
@@ -24,12 +30,14 @@ numerics or, when disabled, costs anything measurable:
   and ``model_residual`` (same definition as
   :func:`repro.tune.measure.model_residual`).
 
-JIT caveat (document once, everywhere): spans wrap *Python* execution.
-Inside ``jax.jit`` they capture trace-time structure - which configs
-resolved, which collectives were scheduled - and their wall time includes
-compilation on the first call; they do not time per-execution device work
-(that is :func:`repro.tune.measure.measure`'s job, which annotates its
-rep statistics onto the enclosing span).
+JIT caveat (document once, everywhere): a captured span's wall time is
+*Python* execution. Inside ``jax.jit`` (or any other JAX transformation)
+it captures trace-time structure - which configs resolved, which
+collectives were scheduled - and its wall time is tracing, a set-up cost.
+Such a span is marked ``traced: true`` and is not priced. Per-execution
+device time comes from the device trace, split by the names the span
+writes into the program, or from :func:`repro.tune.measure.measure`,
+which annotates its rep statistics onto the enclosing span.
 
 ``repro.arch`` is imported lazily inside the finalizer: the import chain
 ``arch -> arch.calibrate -> tune.measure -> obs`` would otherwise cycle.
@@ -40,6 +48,8 @@ import contextlib
 import contextvars
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
+
+import jax
 
 from repro.obs import counters as _counters
 
@@ -137,17 +147,40 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 
+class _Scope:
+    """A span's name in the compiled program (``jax.named_scope``) and on
+    the profiler's host timeline (``jax.profiler.TraceAnnotation``) for
+    its extent; its ``with`` target is :data:`NOOP_SPAN`."""
+
+    __slots__ = ("_scope", "_annotation")
+
+    def __init__(self, name: str):
+        self._scope = jax.named_scope(name)
+        self._annotation = jax.profiler.TraceAnnotation(name)
+
+    def __enter__(self) -> _NoopSpan:
+        self._scope.__enter__()
+        self._annotation.__enter__()
+        return NOOP_SPAN
+
+    def __exit__(self, *exc) -> bool:
+        self._annotation.__exit__(*exc)
+        self._scope.__exit__(*exc)
+        return False
+
+
 class Span:
     """One timed region (or, with ``t_end=None``, one instant event).
 
     Use as a context manager (via :func:`span`); :meth:`annotate` merges
-    attribute dicts at any point before close. Closing computes the
-    derived roofline attrs when ``flops`` is present (see module
-    docstring) and appends the span to its trace.
+    attribute dicts at any point before close. A span opened under a JAX
+    transformation is marked ``traced: true``. Closing computes the
+    derived roofline attrs when ``flops`` is present and the span is not
+    traced (see module docstring) and appends the span to its trace.
     """
 
     __slots__ = ("trace", "name", "cat", "id", "parent", "t_start", "t_end",
-                 "attrs", "_token")
+                 "attrs", "_token", "_scope")
 
     def __init__(self, trace: Trace, name: str, cat: str,
                  attrs: Optional[Dict[str, Any]] = None):
@@ -160,6 +193,7 @@ class Span:
         self.t_end: Optional[float] = None
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         self._token = None
+        self._scope: Optional[_Scope] = None
 
     def annotate(self, **attrs) -> "Span":
         self.attrs.update(attrs)
@@ -173,11 +207,18 @@ class Span:
         self.parent = open_spans[-1].id if open_spans and \
             open_spans[-1].trace is self.trace else None
         self._token = _stack.set(open_spans + (self,))
+        if not jax.core.trace_ctx.is_top_level():
+            self.attrs["traced"] = True
+        self._scope = _Scope(self.name)
+        self._scope.__enter__()
         self.t_start = time.perf_counter() - self.trace.t0
         return self
 
     def __exit__(self, *exc) -> bool:
         self.t_end = time.perf_counter() - self.trace.t0
+        if self._scope is not None:
+            self._scope.__exit__(*exc)
+            self._scope = None
         if self._token is not None:
             _stack.reset(self._token)
             self._token = None
@@ -190,7 +231,9 @@ class Span:
     def _finalize(self) -> None:
         at = self.attrs
         flops = at.get("flops")
-        if flops is None or self.t_end is None or self.t_start is None:
+        # a traced span's wall time is tracing: never priced
+        if flops is None or at.get("traced") or self.t_end is None \
+                or self.t_start is None:
             return
         try:
             from repro import arch                  # lazy: avoid import cycle
@@ -266,13 +309,17 @@ def capture(tr: Optional[Trace]) -> Iterator[Optional[Trace]]:
 
 
 def span(name: str, cat: str = "custom", **attrs):
-    """Open a span under the active trace; a shared no-op when disabled.
+    """Open a span named ``name`` in the program and on the profiler's
+    timeline; it is captured under the active trace, if any.
 
     ``with obs.span("linalg.gemm", cat="routine", flops=2*m*n*k): ...``
+
+    A driver stage is named ``<routine>.<stage>`` (lower case, one dot),
+    e.g. ``getrf.panel``: the device trace is split by such names.
     """
     tr = _current.get()
     if tr is None:
-        return NOOP_SPAN
+        return _Scope(name)
     return Span(tr, name, cat, attrs)
 
 

@@ -61,11 +61,12 @@ def test_span_nesting_and_ids():
 def test_disabled_path_is_noop():
     assert not obs.enabled()
     assert obs.current_trace() is None
-    assert obs.span("x") is obs.NOOP_SPAN
     assert obs.event("x") is None
     assert obs.annotate(a=1) is False
     with obs.span("x") as sp:                       # usable as a with-block
         assert sp is obs.NOOP_SPAN
+        assert sp.annotate(a=1) is sp
+        assert obs.annotate(a=1) is False           # nothing was opened
 
 
 def test_roofline_annotation_prices_flops():
@@ -118,6 +119,76 @@ def test_tracing_is_bitwise_invisible(rng):
     # the obs=False block contributed nothing to the trace
     assert len(tr.spans(name="linalg.qr")) == 1
     assert len(tr.spans(name="linalg.gemm")) == 1
+
+
+# --------------- spans name the compiled program's operations --------------
+
+def _op_names(fn, *args):
+    import jax
+    import re
+    txt = jax.jit(fn).lower(*args).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', txt))
+
+
+@pytest.mark.parametrize("routine, stages", [
+    ("solve", ("linalg.solve", "getrf.panel", "getrf.swap",
+               "getrf.trailing", "gesv.getrs")),
+    ("cholesky", ("linalg.cholesky", "potrf.panel", "potrf.trailing")),
+])
+def test_compiled_program_names_the_driver_stages(rng, routine, stages):
+    """With no trace capturing, every driver stage's name is a segment of
+    the ``op_name`` of the operations it traced, e.g.
+    ``.../getrf.panel/while/body/closed_call/getrf.swap/scatter``."""
+    assert not obs.enabled()
+    a = _mk(rng, (48, 48))
+    if routine == "solve":
+        names = _op_names(lambda a, b: linalg.solve(a, b, block=16),
+                          a, _mk(rng, (48,)))
+    else:
+        spd = a @ a.T / 48 + jnp.eye(48)
+        names = _op_names(lambda a: linalg.cholesky(a, block=16), spd)
+    segments = {seg for name in names for seg in name.split("/")}
+    for stage in stages:
+        assert stage in segments, (stage, sorted(segments))
+
+
+def test_spans_traced_under_jit_are_marked_and_not_priced(rng):
+    import jax
+    a = _mk(rng, (48, 48))
+    spd = a @ a.T / 48 + jnp.eye(48)
+    with obs.trace("jit") as tr:
+        jax.jit(lambda a: linalg.cholesky(a, block=16))(spd)
+        linalg.cholesky(spd, block=16)              # eager: priced
+    jitted, eager = (tr.spans(name="potrf.panel")[:3],
+                     tr.spans(name="potrf.panel")[3:])
+    assert len(jitted) == len(eager) == 3
+    for sp in jitted:
+        assert sp.attrs["traced"] is True
+        assert sp.t_end >= sp.t_start                # tracing's wall time
+        assert "achieved_gflops" not in sp.attrs
+        assert "fraction_of_modeled_peak" not in sp.attrs
+    for sp in eager:
+        assert "traced" not in sp.attrs
+        assert sp.attrs["fraction_of_modeled_peak"] > 0
+
+
+def test_spans_land_on_the_profiler_host_timeline(rng, tmp_path):
+    """An eager call's ``linalg.<op>`` span and its driver stages are host
+    events of a JAX profiler trace, captured or not."""
+    import glob
+    import jax
+    a = _mk(rng, (48, 48))
+    spd = a @ a.T / 48 + jnp.eye(48)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(linalg.cholesky(spd, block=16))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    host = {e.name for p in pd.planes if p.name.startswith("/host:")
+            for line in p.lines for e in line.events}
+    assert {"linalg.cholesky", "potrf.panel", "potrf.trailing"} <= host
 
 
 # ------------------- routine threading (no-mesh leg) ------------------------

@@ -154,9 +154,13 @@ DRIFT_FLOPS_TOL: Dict[str, float] = {
     # row-sequential triangular solves: the traced scan masks the full
     # vector per row, n^2-ish overhead on the n^2 annotation (0.76/0.52)
     "trsv": 0.85, "trsm": 0.70,
-    # blocked factorizations: the masked right-looking implementations
-    # trace full-matrix updates per step (~2n^3 traced volume against the
-    # leading-order n^3/3-style coefficients; measured 0.67-0.93). The
+    # factorizations: the masked unblocked steps trace a full-width
+    # update per column (~2n^3 traced volume against the leading-order
+    # n^3/3-style coefficients; measured 0.67-0.93). At the lint shape
+    # (n = 64, one panel at the default block) lu and solve run
+    # getrf's unblocked dgetf2 over the whole matrix (measured 0.836
+    # and 0.803); the blocked getrf factors each panel alone, so its
+    # drift is lower (0.66 lu, 0.64 solve at n = 64, block 16). The
     # band is tight in ratio terms: a complexity-class regression (an
     # accidental O(n^4) update) lands at drift > 0.98 and still fires.
     "cholesky": 0.90, "lu": 0.90, "qr": 0.80, "solve": 0.88, "lstsq": 0.96,

@@ -15,6 +15,48 @@ from repro import obs as _obs
 from repro.lapack.cholesky import default_block
 
 
+def _getf2(p: jnp.ndarray
+           ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """LAPACK's dgetf2 on ``p`` alone: unblocked LU with partial pivoting.
+
+    Each step picks the pivot by argmax, swaps rows, scales the column
+    and applies the rank-1 update to the columns on its right. Returns
+    ``(packed, piv, perm)``: the packed L\\U, the 0-based ipiv of
+    :func:`getrf_unblocked`, and the rows' permutation (row i of
+    ``packed`` comes from row ``perm[i]`` of ``p``), which the blocked
+    driver applies to the columns outside the panel.
+    """
+    m, w = p.shape
+    rows = jnp.arange(m)
+    cols = jnp.arange(w)
+
+    def body(k, carry):
+        A, piv, perm = carry
+        col = jnp.where(rows >= k, jnp.abs(A[:, k]), -jnp.inf)
+        # pin to int32 (LAPACK ipiv width): under JAX_ENABLE_X64 argmax
+        # yields int64, and scattering that into the int32 piv buffer is
+        # a dtype-mismatch error in future JAX (analysis rule DF family)
+        q = jnp.argmax(col).astype(jnp.int32)
+        with _obs.span("getrf.swap", cat="swap"):
+            piv = piv.at[k].set(q)
+            rk, rq = A[k], A[q]
+            A = A.at[k].set(rq).at[q].set(rk)
+            pk, pq = perm[k], perm[q]
+            perm = perm.at[k].set(pq).at[q].set(pk)
+        pivval = A[k, k]
+        safe = jnp.where(jnp.abs(pivval) > 0, pivval, 1.0)
+        l = jnp.where(rows > k, A[:, k] / safe, 0.0)
+        A = A.at[:, k].set(jnp.where(rows > k, l, A[:, k]))
+        urow = jnp.where(cols > k, A[k], 0.0)
+        A = A - jnp.outer(l, urow)
+        return A, piv, perm
+
+    kmax = min(m, w)
+    return lax.fori_loop(0, kmax, body,
+                         (p, jnp.zeros((kmax,), jnp.int32),
+                          jnp.arange(m, dtype=jnp.int32)))
+
+
 def getrf_unblocked(a: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Unblocked LU with partial pivoting of one matrix.
 
@@ -33,30 +75,7 @@ def getrf_unblocked(a: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     -----
     Oracle: ``tests/test_lapack.py`` (vs ``scipy.linalg.lu_factor``).
     """
-    n = a.shape[0]
-    rows = jnp.arange(n)
-
-    def body(k, carry):
-        A, piv = carry
-        col = jnp.where(rows >= k, jnp.abs(A[:, k]), -jnp.inf)
-        # pin to int32 (LAPACK ipiv width): under JAX_ENABLE_X64 argmax
-        # yields int64, and scattering that into the int32 piv buffer is
-        # a dtype-mismatch error in future JAX (analysis rule DF family)
-        p = jnp.argmax(col).astype(jnp.int32)
-        piv = piv.at[k].set(p)
-        rk, rp = A[k], A[p]
-        A = A.at[k].set(rp).at[p].set(rk)
-        pivval = A[k, k]
-        safe = jnp.where(jnp.abs(pivval) > 0, pivval, 1.0)
-        l = jnp.where(rows > k, A[:, k] / safe, 0.0)
-        A = A.at[:, k].set(jnp.where(rows > k, l, A[:, k]))
-        urow = jnp.where(jnp.arange(A.shape[1]) > k, A[k], 0.0)
-        A = A - jnp.outer(l, urow)
-        return A, piv
-
-    A, piv = lax.fori_loop(0, min(a.shape), body,
-                           (a, jnp.zeros((min(a.shape),), jnp.int32)))
-    return A, piv
+    return _getf2(a)[:2]
 
 
 def getrf(a: jnp.ndarray, block: Optional[int] = None,
@@ -64,6 +83,13 @@ def getrf(a: jnp.ndarray, block: Optional[int] = None,
           interpret: Optional[bool] = None, registry=None,
           fuse: Optional[bool] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Blocked right-looking LU with partial pivoting (LAPACK DGETRF).
+
+    Each panel of ``block`` columns is factored as an (m - j0) x nb array
+    of its own (LAPACK's dgetf2: pivot, swap, scale, rank-1 update within
+    the panel). Its row interchanges then reach the columns left and
+    right of the panel once, as one gather and scatter of at most 2 nb
+    rows (LAPACK's dlaswp), before the trailing TRSM + GEMM. No step of
+    the panel loop touches the rest of the matrix.
 
     Parameters
     ----------
@@ -108,35 +134,21 @@ def getrf(a: jnp.ndarray, block: Optional[int] = None,
     if kmax <= block:
         return getrf_unblocked(a)
     pivs = []
-    rows = jnp.arange(n)
     for j0 in range(0, kmax, block):
         nb = min(block, kmax - j0)
-        # panel factorization over full remaining height, swaps applied to
-        # the whole width (LAPACK's laswp).
-        def pbody(kk, carry):
-            A, piv = carry
-            k = j0 + kk
-            col = jnp.where(rows >= k, jnp.abs(A[:, k]), -jnp.inf)
-            p = jnp.argmax(col).astype(jnp.int32)   # ipiv stays int32 (x64)
-            with _obs.span("getrf.swap", cat="swap"):
-                piv = piv.at[kk].set(p)
-                rk, rp = A[k], A[p]
-                A = A.at[k].set(rp).at[p].set(rk)
-            pivval = A[k, k]
-            safe = jnp.where(jnp.abs(pivval) > 0, pivval, 1.0)
-            l = jnp.where(rows > k, A[:, k] / safe, 0.0)
-            A = A.at[:, k].set(jnp.where(rows > k, l, A[:, k]))
-            # rank-1 update restricted to the panel's remaining columns
-            urow = jnp.where((jnp.arange(nc) > k) & (jnp.arange(nc) < j0 + nb),
-                             A[k], 0.0)
-            A = A - jnp.outer(l, urow)
-            return A, piv
-
         with _obs.span("getrf.panel", cat="panel", j0=j0, nb=nb,
                        flops=(n - j0) * nb * nb):
-            a, piv = lax.fori_loop(0, nb, pbody,
-                                   (a, jnp.zeros((nb,), jnp.int32)))
-        pivs.append(piv)
+            # dgetf2 on the (n - j0) x nb panel alone ...
+            pf, piv, perm = _getf2(a[j0:, j0:j0 + nb])
+            with _obs.span("getrf.swap", cat="swap"):
+                # ... then dlaswp: the panel's interchanges on the other
+                # columns, once. Only the panel's first nb local rows and
+                # its pivot rows can move, so 2 nb rows are gathered and
+                # scattered (a repeated index writes the same row twice)
+                idx = jnp.concatenate([jnp.arange(nb, dtype=jnp.int32), piv])
+                a = a.at[j0 + idx].set(a[j0 + perm[idx]])
+            a = a.at[j0:, j0:j0 + nb].set(pf)
+        pivs.append(piv + j0)
         if j0 + nb < nc:
             mr, ncr = n - j0 - nb, nc - j0 - nb     # trailing block dims
             with _obs.span("getrf.trailing", cat="trailing", j0=j0, nb=nb,

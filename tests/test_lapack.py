@@ -52,6 +52,78 @@ def test_lu_blocked_equals_unblocked(rng):
     assert bool(jnp.all(v1 == v2))
 
 
+def _getrf_whole_matrix_panels(a, block):
+    """Oracle: the blocked LU whose panel loop carries the whole matrix,
+    swapping full rows and masking the rank-1 update to the panel at
+    every step (the driver before dgetf2 ran on the panel alone)."""
+    from jax import lax
+    from repro.tune import dispatch as _tune
+    n, nc = a.shape
+    kmax = min(n, nc)
+    if kmax <= block:
+        return lapack.getrf_unblocked(a)
+    pivs, rows = [], jnp.arange(n)
+    for j0 in range(0, kmax, block):
+        nb = min(block, kmax - j0)
+
+        def pbody(kk, carry, j0=j0, nb=nb):
+            A, piv = carry
+            k = j0 + kk
+            col = jnp.where(rows >= k, jnp.abs(A[:, k]), -jnp.inf)
+            p = jnp.argmax(col).astype(jnp.int32)
+            piv = piv.at[kk].set(p)
+            rk, rp = A[k], A[p]
+            A = A.at[k].set(rp).at[p].set(rk)
+            pivval = A[k, k]
+            safe = jnp.where(jnp.abs(pivval) > 0, pivval, 1.0)
+            l = jnp.where(rows > k, A[:, k] / safe, 0.0)
+            A = A.at[:, k].set(jnp.where(rows > k, l, A[:, k]))
+            cols = jnp.arange(nc)
+            urow = jnp.where((cols > k) & (cols < j0 + nb), A[k], 0.0)
+            return A - jnp.outer(l, urow), piv
+
+        a, piv = lax.fori_loop(0, nb, pbody, (a, jnp.zeros((nb,), jnp.int32)))
+        pivs.append(piv)
+        if j0 + nb < nc:
+            u12, c_out = _tune.dispatch(
+                "trsm+gemm", a[j0:j0 + nb, j0:j0 + nb],
+                a[j0:j0 + nb, j0 + nb:], a[j0 + nb:, j0:j0 + nb],
+                a[j0 + nb:, j0 + nb:], form="lu", unit_diag=True,
+                policy="reference")
+            a = a.at[j0:j0 + nb, j0 + nb:].set(u12)
+            a = a.at[j0 + nb:, j0 + nb:].set(c_out)
+    return a, jnp.concatenate(pivs)
+
+
+@pytest.mark.parametrize("m,n,block,batched", [
+    (64, 64, 16, False),      # square
+    (96, 40, 16, False),      # tall
+    (40, 96, 16, False),      # wide
+    (70, 70, 16, False),      # n not a multiple of the block
+    (24, 24, 64, False),      # a single panel
+    (48, 48, 16, True),       # vmap through batched_getrf
+])
+def test_lu_panel_alone_matches_whole_matrix_panels(rng, m, n, block,
+                                                    batched):
+    """dgetf2 on the panel plus one dlaswp per panel does the whole-matrix
+    panel loop's arithmetic element for element: same pivots, same
+    packed factors, bit for bit."""
+    if batched:
+        a = jnp.asarray(rng.normal(size=(3, m, n)).astype(np.float32))
+        res = jax.jit(lambda x: lapack.batched_getrf(
+            x, block=block, policy="reference"))(a)
+        got = res.factors, res.pivots
+        want = jax.jit(jax.vmap(
+            lambda x: _getrf_whole_matrix_panels(x, block)))(a)
+    else:
+        a = _rand(rng, m, n)
+        got = jax.jit(lambda x: lapack.getrf(x, block=block,
+                                             policy="reference"))(a)
+        want = jax.jit(lambda x: _getrf_whole_matrix_panels(x, block))(a)
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+
+
 @pytest.mark.parametrize("block", [8, 999])
 def test_cholesky(rng, block):
     a = _rand(rng, 32, 32)

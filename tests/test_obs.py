@@ -152,6 +152,64 @@ def test_compiled_program_names_the_driver_stages(rng, routine, stages):
         assert stage in segments, (stage, sorted(segments))
 
 
+def _largest_writes(hlo_text, stage_pattern):
+    """{instruction: elements it writes} for the compiled instructions
+    whose ``op_name`` matches ``stage_pattern``. An in-place update
+    (``dynamic-update-slice``, ``scatter``, or a fusion rooted in one)
+    writes its update operand, not its whole result; any other
+    instruction writes its result (a tuple: its largest element)."""
+    import re
+    instr = re.compile(r"\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*?)\s"
+                       r"([a-z][\w\-]*)\((.*)$")
+    update_operand = {"dynamic-update-slice": 1, "scatter": 2}
+
+    def elements(typ):
+        return max([int(np.prod([int(d) for d in dims.split(",") if d]))
+                    for dims in re.findall(r"\w+\[([\d,]*)\]", typ)] or [0])
+
+    types, roots, ops, comp = {}, {}, [], None
+    for line in hlo_text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            comp = line.replace("ENTRY ", "").split()[0].lstrip("%")
+        m = instr.match(line)
+        if not m:
+            continue
+        root, name, typ, opcode, rest = m.groups()
+        operands = re.findall(r"%([\w.\-]+)", rest.split("),")[0])
+        types[name] = typ
+        if root:
+            roots[comp] = (opcode, operands)
+        callee = re.search(r"\bcalls=%?([\w.\-]+)", rest)
+        op_name = re.search(r'op_name="([^"]*)"', rest)
+        if op_name and re.search(stage_pattern, op_name.group(1)):
+            ops.append((name, typ, opcode, operands,
+                        callee and callee.group(1)))
+    out = {}
+    for name, typ, opcode, operands, callee in ops:
+        if opcode == "fusion" and roots.get(callee, ("",))[0] \
+                in update_operand:
+            opcode, operands = roots[callee]
+        if opcode in update_operand:
+            typ = types[operands[update_operand[opcode]]]
+        out[name] = elements(typ)
+    return out
+
+
+def test_lu_panel_and_swaps_never_pass_over_the_whole_matrix(rng):
+    """The LU panel is factored on the (n - j0) x nb panel alone and its
+    row interchanges touch 2 nb rows: no operation under ``getrf.panel``
+    or ``getrf.swap`` writes n x n elements or more."""
+    import jax
+    n = 512
+    txt = jax.jit(lambda a, b: linalg.solve(a, b, block=64)).lower(
+        _mk(rng, (n, n)), _mk(rng, (n,))).compile().as_text()
+    writes = _largest_writes(txt, r"getrf\.(panel|swap)")
+    assert writes, "no operation named by the LU panel's spans"
+    assert max(writes.values()) >= 64 * 64       # the panel is there ...
+    big = {k: v for k, v in writes.items() if v >= n * n}
+    assert not big, big                          # ... the matrix is not
+
+
 def test_spans_traced_under_jit_are_marked_and_not_priced(rng):
     import jax
     a = _mk(rng, (48, 48))

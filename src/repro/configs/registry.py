@@ -21,7 +21,7 @@ from repro.models.frontends import frontend_tokens
 ARCHS = (
     "minitron-8b", "granite-3-8b", "gemma-7b", "mistral-large-123b",
     "whisper-small", "mamba2-130m", "hymba-1.5b", "internvl2-1b",
-    "qwen3-moe-235b-a22b", "kimi-k2-1t-a32b",
+    "qwen3-moe-235b-a22b", "kimi-k2-1t-a32b", "granite-4.0-h-micro",
 )
 
 _MODULES = {a: a.replace("-", "_").replace(".", "_") for a in ARCHS}

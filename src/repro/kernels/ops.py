@@ -13,6 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs import counters as _counters
 from repro.kernels import dotp as _dotp
 from repro.kernels import flash_attention as _fa
 from repro.kernels import gemm as _gemm
@@ -70,6 +71,7 @@ def attention(q, k, v, causal: bool = True, scale=None, q_offset: int = 0,
                                          q_offset=q_offset, window=window)
         return ref.attention(q, k, v, causal=causal, scale=scale,
                              q_offset=q_offset, window=window)
+    _counters.inc("kernel.flash_attention")
     return _fa.attention(q, k, v, causal=causal, scale=scale,
                          q_offset=q_offset, window=window, kv_len=kv_len,
                          interpret=interpret,
@@ -77,16 +79,21 @@ def attention(q, k, v, causal: bool = True, scale=None, q_offset: int = 0,
 
 
 def ssd(x, a_log, B, C, chunk=None, use_pallas: Optional[bool] = None,
-        interpret: Optional[bool] = None):
+        interpret: Optional[bool] = None, return_state: bool = False):
     """SSD in model layout: x (B, L, H, P), a_log (B, L, H), B/C (B, L, H, N).
-    Returns y (B, L, H, P)."""
+    Returns y (B, L, H, P), and with ``return_state`` the final state
+    (B, H, P, N) in float32."""
     use = _on_tpu() if use_pallas is None else use_pallas
     if not use:
-        return ref.ssd_chunked(x, a_log, B, C, chunk=chunk or 64)
+        return ref.ssd_chunked(x, a_log, B, C, chunk=chunk or 64,
+                               return_state=return_state)
+    _counters.inc("kernel.ssd_scan")
     xt = jnp.moveaxis(x, 2, 1)             # (B,H,L,P)
     at = jnp.moveaxis(a_log, 2, 1)         # (B,H,L)
     Bt = jnp.moveaxis(B, 2, 1)
     Ct = jnp.moveaxis(C, 2, 1)
-    y = _ssd.ssd_scan(xt, at, Bt, Ct, chunk=chunk,
-                      interpret=interpret)
-    return jnp.moveaxis(y, 1, 2)
+    out = _ssd.ssd_scan(xt, at, Bt, Ct, chunk=chunk, interpret=interpret,
+                        return_state=return_state)
+    if return_state:
+        return jnp.moveaxis(out[0], 1, 2), out[1]
+    return jnp.moveaxis(out, 1, 2)

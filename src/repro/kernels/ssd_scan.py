@@ -11,7 +11,8 @@ busy/non-busy split of eq. 1.
 
 Layout (pre-arranged by ops.ssd): x (B, H, L, P), a_log (B, H, L),
 B/C (B, H, L, N). Grid (B, H, L/c), chunk dim sequential; fp32 (P, N) state
-carried in VMEM scratch across chunks.
+carried in VMEM scratch across chunks, and written out after the last chunk
+when the caller asks for it (a prefill hands it to decode).
 """
 from __future__ import annotations
 
@@ -27,7 +28,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.codesign import plan_ssd
 
 
-def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, chunk: int):
+def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, *rest, chunk: int,
+                nc: int):
+    *final_ref, state_ref = rest
     i = pl.program_id(2)
 
     @pl.when(i == 0)
@@ -72,11 +75,17 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *, chunk: int):
                                         preferred_element_type=jnp.float32))
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
+    if final_ref:
+        @pl.when(i == nc - 1)
+        def _final():
+            final_ref[0][0, 0] = state_ref[...]
+
 
 def ssd_scan(x: jnp.ndarray, a_log: jnp.ndarray, B: jnp.ndarray,
              C: jnp.ndarray, chunk: int | None = None,
-             interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Chunked SSD over (B, H, L, ...) layout; returns y (B, H, L, P).
+             interpret: Optional[bool] = None, return_state: bool = False):
+    """Chunked SSD over (B, H, L, ...) layout; returns y (B, H, L, P), and
+    with ``return_state`` also the final state (B, H, P, N) in float32.
 
     ``a_log`` streams as one (1, chunk) row per grid step, so on a TPU the
     chunk is a multiple of 128 or spans the whole (padded) sequence.
@@ -88,7 +97,11 @@ def ssd_scan(x: jnp.ndarray, a_log: jnp.ndarray, B: jnp.ndarray,
         # batch/head/length/feature axes make y empty, and an empty state
         # axis N zeroes every contribution - jnp zeros of x's shape is
         # the exact answer either way
-        return jnp.zeros(x.shape, x.dtype)
+        y = jnp.zeros(x.shape, x.dtype)
+        if return_state:
+            return y, jnp.zeros(x.shape[:2] + (x.shape[-1], B.shape[-1]),
+                                jnp.float32)
+        return y
     bsz, h, L, p = x.shape
     n = B.shape[-1]
     if chunk is None:
@@ -105,8 +118,15 @@ def ssd_scan(x: jnp.ndarray, a_log: jnp.ndarray, B: jnp.ndarray,
         from repro.kernels.ops import interpret_mode    # ops imports this
         interpret = interpret_mode()
     a_rows = a_log[:, :, None, :]                        # (B, H, 1, L)
-    y = pl.pallas_call(
-        functools.partial(_ssd_kernel, chunk=chunk),
+    out_specs = [pl.BlockSpec((1, 1, chunk, p),
+                              lambda b_, h_, i: (b_, h_, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((bsz, h, L + pad, p), x.dtype)]
+    if return_state:    # one (P, N) block per (batch, head), resident
+        out_specs.append(pl.BlockSpec((1, 1, p, n),
+                                      lambda b_, h_, i: (b_, h_, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32))
+    out = pl.pallas_call(
+        functools.partial(_ssd_kernel, chunk=chunk, nc=nc),
         grid=(bsz, h, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, i: (b_, h_, i, 0)),
@@ -114,11 +134,13 @@ def ssd_scan(x: jnp.ndarray, a_log: jnp.ndarray, B: jnp.ndarray,
             pl.BlockSpec((1, 1, chunk, n), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, chunk, n), lambda b_, h_, i: (b_, h_, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, i: (b_, h_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bsz, h, L + pad, p), x.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, a_rows, B, C)
-    return y[:, :, :L]
+    if return_state:
+        return out[0][:, :, :L], out[1]
+    return out[0][:, :, :L]

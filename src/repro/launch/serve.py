@@ -76,17 +76,21 @@ def _serve_batch(params, cfg, requests: List[Request], max_len: int,
                    model=cfg.name, prompt_max=pmax):
         with _obs.span("serve.prefill", cat="serve", batch=b,
                        prompt_max=pmax):
-            # prefill the whole padded batch (cache layout matches decode)
-            logits, _, _ = zoo.prefill(params, batch, cfg, use_pallas=False)
-            caches = zoo.init_caches(params, cfg, b, max_len)
-            # replay prompts through decode_step to fill caches (simple +
-            # exact; a production server would scatter the prefill KVs
-            # directly)
             step = jax.jit(lambda p, t, c, i: zoo.decode_step(p, t, cfg, c, i))
-            last = None
-            for t in range(pmax):
-                last, caches = step(params, jnp.asarray(toks[:, t:t + 1]),
-                                    caches, jnp.int32(t))
+            if cfg.layer_pattern:
+                # a patterned stack's prefill hands decode its caches (KV
+                # of max_len slots, SSM state and conv tail) directly
+                last, _, caches = zoo.prefill(params, batch, cfg,
+                                              use_pallas=False,
+                                              max_len=max_len)
+            else:
+                # replay prompts through decode_step to fill caches (simple
+                # + exact; the other families' prefill returns no cache in
+                # decode's layout)
+                caches = zoo.init_caches(params, cfg, b, max_len)
+                for t in range(pmax):
+                    last, caches = step(params, jnp.asarray(toks[:, t:t + 1]),
+                                        caches, jnp.int32(t))
 
         key = jax.random.PRNGKey(seed)
         out = [list(r.prompt) for r in requests]

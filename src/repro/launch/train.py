@@ -34,6 +34,9 @@ def reduce_config(cfg, layers=None, d_model=None, vocab=None, heads=None):
     """Shrink an assigned config to laptop scale, same family/topology."""
     upd = {}
     if layers:
+        if cfg.layer_pattern:     # whole periods of the pattern
+            period = len(cfg.layer_pattern)
+            layers = -(-layers // period) * period
         upd["n_layers"] = layers
         upd["global_layers"] = tuple(
             i for i in cfg.global_layers if i < layers) or ((0,) if cfg.family == "hybrid" else ())
@@ -43,6 +46,7 @@ def reduce_config(cfg, layers=None, d_model=None, vocab=None, heads=None):
         ratio = d_model / cfg.d_model
         upd["d_model"] = d_model
         upd["d_ff"] = max(32, int(cfg.d_ff * ratio)) if cfg.d_ff else 0
+        upd["ssm_heads"] = 0                 # d_inner / ssm_head_dim
         if cfg.family == "moe":
             upd["d_expert"] = max(32, int((cfg.d_expert or cfg.d_ff) * ratio))
             upd["n_experts"] = min(cfg.n_experts, 8)

@@ -1,4 +1,7 @@
-"""GQA attention with RoPE, KV cache, sliding windows, and cross-attention.
+"""GQA attention with RoPE or none (NoPE), KV cache, sliding windows, and
+cross-attention. The softmax scale is the config's ``attention_multiplier``
+where it sets one, else 1/sqrt(head_dim); ``attn.core`` names the softmax
+attention itself in the compiled program.
 
 Training path uses the flash oracle (Pallas kernel on TPU via ops.attention);
 decode path writes one token into the cache and attends with a kv-length
@@ -12,6 +15,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ops
 from repro.models.config import ModelConfig
 from repro.models.layers import init_linear, rope, truncated_normal
@@ -64,18 +68,23 @@ def _project_qkv(p, x, cfg: ModelConfig, positions, dtype, use_rope=True):
 
 def apply_attention(p, x, cfg: ModelConfig, positions,
                     window: Optional[int] = None, causal: bool = True,
-                    use_pallas: Optional[bool] = None) -> jnp.ndarray:
-    """Full-sequence (training / prefill) attention. x: (B, S, d)."""
+                    use_pallas: Optional[bool] = None, return_kv: bool = False):
+    """Full-sequence (training / prefill) attention. x: (B, S, d). With
+    ``return_kv`` also ``{"k", "v"}`` (B, S, Hkv, hd), what a prefill
+    leaves in the cache."""
     dtype = x.dtype
     q, k, v = _project_qkv(p, x, cfg, positions, dtype)
-    qh = jnp.moveaxis(q, 2, 1)                    # (B, Hq, S, hd)
-    kh = jnp.moveaxis(k, 2, 1)
-    vh = jnp.moveaxis(v, 2, 1)
-    o = ops.attention(qh, kh, vh, causal=causal, window=window,
-                      use_pallas=use_pallas)
+    with obs.span("attn.core"):
+        qh = jnp.moveaxis(q, 2, 1)                # (B, Hq, S, hd)
+        kh = jnp.moveaxis(k, 2, 1)
+        vh = jnp.moveaxis(v, 2, 1)
+        o = ops.attention(qh, kh, vh, causal=causal, window=window,
+                          scale=cfg.attention_multiplier,
+                          use_pallas=use_pallas)
     b, s = x.shape[:2]
     o = jnp.moveaxis(o, 1, 2).reshape(b, s, cfg.n_heads * cfg.hd)
-    return o @ p["wo"].astype(dtype)
+    o = o @ p["wo"].astype(dtype)
+    return (o, {"k": k, "v": v}) if return_kv else o
 
 
 def apply_attention_decode(p, x, cfg: ModelConfig, cache, write_idx,
@@ -102,16 +111,18 @@ def apply_attention_decode(p, x, cfg: ModelConfig, cache, write_idx,
     qh = jnp.moveaxis(q, 2, 1)                    # (B, Hq, 1, hd)
     kh = jnp.moveaxis(ck, 2, 1).astype(dtype)     # (B, Hkv, Smax, hd)
     vh = jnp.moveaxis(cv, 2, 1).astype(dtype)
-    o = masked_decode_attention(qh, kh, vh, kv_len)
+    o = masked_decode_attention(qh, kh, vh, kv_len,
+                                scale=cfg.attention_multiplier)
     o = jnp.moveaxis(o, 1, 2).reshape(b, 1, cfg.n_heads * cfg.hd)
     return o @ p["wo"].astype(dtype), {"k": ck, "v": cv}
 
 
-def masked_decode_attention(q, k, v, kv_len):
+def masked_decode_attention(q, k, v, kv_len, scale=None):
     """Reference decode attention with explicit kv-len mask (fp32 softmax).
 
     q: (B, Hq, 1, hd); k/v: (B, Hkv, Smax, hd). Replaced per-shard by the
-    flash-decoding shard_map in the distributed serve path.
+    flash-decoding shard_map in the distributed serve path. ``scale``
+    ``None`` is 1/sqrt(hd).
     """
     b, hq, _, hd = q.shape
     hkv = k.shape[1]
@@ -119,7 +130,8 @@ def masked_decode_attention(q, k, v, kv_len):
     qf = q.astype(jnp.float32).reshape(b, hkv, group, hd)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
-    logits = jnp.einsum("bhgd,bhkd->bhgk", qf, kf) / (hd ** 0.5)
+    logits = jnp.einsum("bhgd,bhkd->bhgk", qf, kf)
+    logits = logits / (hd ** 0.5) if scale is None else logits * scale
     kpos = jnp.arange(k.shape[2])
     mask = kpos[None, :] < kv_len
     logits = jnp.where(mask[None, None], logits, -1e30)

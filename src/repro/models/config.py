@@ -1,9 +1,10 @@
 """Model configuration - one dataclass covers the whole assigned pool.
 
-Families: dense (GQA transformer), moe (dense + expert FFNs), ssm (Mamba-2),
-hybrid (parallel attn+SSM heads, Hymba-style), encdec (Whisper-style),
-vlm/audio (LM backbone + stub modality frontend feeding precomputed
-embeddings).
+Families: dense (GQA transformer), moe (dense + expert FFNs), ssm (a stack
+of Mamba-2 layers, optionally interleaved with attention layers by
+``layer_pattern``), hybrid (parallel attn+SSM heads, Hymba-style), encdec
+(Whisper-style), vlm/audio (LM backbone + stub modality frontend feeding
+precomputed embeddings).
 """
 from __future__ import annotations
 
@@ -45,6 +46,11 @@ class ModelConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 64
 
+    # layer stack by kind: one period of "mamba" | "attention" layers,
+    # repeated n_layers / len(layer_pattern) times (the ssm family); every
+    # layer is followed by the FFN when d_ff > 0. Empty: one uniform block.
+    layer_pattern: Tuple[str, ...] = ()
+
     # hybrid (Hymba)
     window: Optional[int] = None          # sliding window for local layers
     global_layers: Tuple[int, ...] = ()   # full-attention layer indices
@@ -59,9 +65,17 @@ class ModelConfig:
 
     # positions / norm
     rope_theta: float = 10_000.0
-    pos: str = "rope"                     # rope | sinusoidal
+    pos: str = "rope"                     # rope | sinusoidal | none (NoPE)
     norm_eps: float = 1e-6
     logit_softcap: Optional[float] = None
+
+    # muP multipliers (Granite): the embedding is scaled up, each residual
+    # branch scaled, the softmax scale set (None -> 1/sqrt(head_dim)), the
+    # logits divided; the defaults leave a model unchanged
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
 
     # numerics / compilation
     dtype: str = "bfloat16"
@@ -86,20 +100,30 @@ class ModelConfig:
     def n_ssm_heads(self) -> int:
         return self.ssm_heads or self.d_inner // self.ssm_head_dim
 
+    def __post_init__(self):
+        if self.layer_pattern and self.n_layers % len(self.layer_pattern):
+            raise ValueError(f"{self.name}: {self.n_layers} layers are not "
+                             f"whole periods of {self.layer_pattern}")
+
     @property
     def attention_free(self) -> bool:
-        return self.family == "ssm"
+        return self.family == "ssm" and "attention" not in self.layer_pattern
 
     @property
     def sub_quadratic(self) -> bool:
-        """Can this arch run long_500k? SSM and windowed-hybrid: yes."""
-        return self.family == "ssm" or (self.family == "hybrid"
-                                        and self.window is not None)
+        """Can this arch run long_500k? Attention-free SSM and windowed
+        hybrid: yes; one full-attention layer in the pattern makes it no."""
+        return self.attention_free or (self.family == "hybrid"
+                                       and self.window is not None)
+
+    def layer_kind(self, i: int) -> str:
+        """Kind of layer ``i`` of a patterned stack."""
+        return self.layer_pattern[i % len(self.layer_pattern)]
 
     def param_count(self) -> int:
         """Analytical parameter count (for 6ND roofline math)."""
         d, v = self.d_model, self.vocab
-        n = v * d                                           # embedding
+        n = v * d + d                                 # embedding, final norm
         if not self.tie_embeddings:
             n += d * v                                      # lm head
         for i in range(self.n_layers):
@@ -140,15 +164,17 @@ class ModelConfig:
         d, di = self.d_model, self.d_inner
         g, nst, h = self.ssm_groups, self.ssm_state, self.n_ssm_heads
         in_proj = d * (2 * di + 2 * g * nst + h)
-        conv = (di + 2 * g * nst) * self.ssm_conv
-        return in_proj + conv + 2 * h + di + di * d       # A, dt_bias, norm, out
+        conv = (di + 2 * g * nst) * (self.ssm_conv + 1)   # weights + bias
+        return in_proj + conv + 3 * h + di + di * d   # A, dt_bias, D, norm, out
 
     def _layer_params(self, i: int) -> int:
         d = self.d_model
+        if self.layer_pattern:
+            mixer = (self._ssm_params() if self.layer_kind(i) == "mamba"
+                     else self._attn_params())
+            ffn = d + self._ffn_params() if self.d_ff else 0   # ln2 + FFN
+            return d + mixer + ffn
         n = 2 * d                                          # two rmsnorms
-        if self.family == "ssm":
-            return n + self._ssm_params() + self._ffn_params() \
-                if self.d_ff else n + self._ssm_params()
         if self.family == "hybrid":
             return n + self._attn_params() + self._ssm_params() // 2 \
                 + self._ffn_params()

@@ -2,7 +2,13 @@
 
 Prefill/training run the chunked SSD (Pallas kernel on TPU, jnp oracle here);
 decode is the O(1) per-token recurrence against a cached (H, P, N) state +
-conv tail - the reason ``long_500k`` is feasible for SSM archs at all.
+conv tail - the reason ``long_500k`` is feasible for SSM archs at all. A
+prefill hands both to decode (``return_state``).
+
+Per head: h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T, y_t = C_t h_t + D x_t
+(the skip term takes x before the dt scaling, as Mamba-2 does). The spans
+``mamba.conv``, ``mamba.ssd`` and ``mamba.out`` name the block's stages in
+the compiled program.
 """
 from __future__ import annotations
 
@@ -11,7 +17,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref
+from repro import obs
+from repro.kernels import ops
 from repro.models.config import ModelConfig
 from repro.models.layers import init_rmsnorm, apply_rmsnorm, truncated_normal
 
@@ -65,7 +72,9 @@ def _causal_conv(u: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
 
 
 def _prepare_ssd(xs, B, C, dt, p, cfg: ModelConfig):
-    """Shared head-reshape + dt/A handling for prefill and decode."""
+    """Shared head-reshape + dt/A handling for prefill and decode: the
+    heads' inputs x (B,S,H,P), the SSD's input x * dt, log-decay dt * A,
+    and B/C per head."""
     bsz, s, _ = xs.shape
     h, hd = cfg.n_ssm_heads, cfg.ssm_head_dim
     g, n = cfg.ssm_groups, cfg.ssm_state
@@ -73,31 +82,49 @@ def _prepare_ssd(xs, B, C, dt, p, cfg: ModelConfig):
                          + p["dt_bias"].astype(jnp.float32))    # (B,S,H)
     a = -jnp.exp(p["a_log"].astype(jnp.float32))                # (H,)
     a_log_dt = dt * a[None, None, :]                            # (B,S,H) <= 0
-    xh = xs.reshape(bsz, s, h, hd) * dt[..., None].astype(xs.dtype)
+    x_heads = xs.reshape(bsz, s, h, hd)
+    xh = x_heads * dt[..., None].astype(xs.dtype)
     rep = h // g
     Bh = jnp.repeat(B.reshape(bsz, s, g, n), rep, axis=2)
     Ch = jnp.repeat(C.reshape(bsz, s, g, n), rep, axis=2)
-    return xh, a_log_dt, Bh, Ch
+    return x_heads, xh, a_log_dt, Bh, Ch
+
+
+def _skip(y, x_heads, p):
+    """Mamba-2's skip term: y + D x, x before the dt scaling."""
+    return y + x_heads * p["d_skip"].astype(y.dtype)[None, None, :, None]
+
+
+def _gated_norm(p, y, z, cfg: ModelConfig):
+    y = y.reshape(*z.shape[:2], cfg.d_inner)
+    return apply_rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
 
 
 def apply_mamba(p, x: jnp.ndarray, cfg: ModelConfig,
-                use_pallas: Optional[bool] = None) -> jnp.ndarray:
-    """Full-sequence path. x: (B, S, d)."""
+                use_pallas: Optional[bool] = None, return_state: bool = False):
+    """Full-sequence path. x: (B, S, d). With ``return_state`` also the
+    cache decode continues from: ``{"state": (B,H,P,N) f32, "conv": the
+    last K-1 conv inputs}``."""
     dtype = x.dtype
-    bsz, s, _ = x.shape
     di = cfg.d_inner
     gn = cfg.ssm_groups * cfg.ssm_state
-    zxbcdt = x @ p["in_proj"].astype(dtype)
-    z, xs, B, C, dt = _split_proj(zxbcdt, cfg)
-    xbc = jnp.concatenate([xs, B, C], axis=-1)
-    xbc, _ = _causal_conv(xbc, p["conv_w"], p["conv_b"])
-    xs, B, C = xbc[..., :di], xbc[..., di:di + gn], xbc[..., di + gn:]
-    xh, a_log, Bh, Ch = _prepare_ssd(xs, B, C, dt, p, cfg)
-    y = ops.ssd(xh, a_log, Bh, Ch, chunk=cfg.ssm_chunk, use_pallas=use_pallas)
-    y = y + xh * p["d_skip"].astype(dtype)[None, None, :, None]
-    y = y.reshape(bsz, s, di)
-    y = apply_rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
-    return y @ p["out_proj"].astype(dtype)
+    with obs.span("mamba.conv"):
+        zxbcdt = x @ p["in_proj"].astype(dtype)
+        z, xs, B, C, dt = _split_proj(zxbcdt, cfg)
+        xbc = jnp.concatenate([xs, B, C], axis=-1)
+        xbc, tail = _causal_conv(xbc, p["conv_w"], p["conv_b"])
+        xs, B, C = xbc[..., :di], xbc[..., di:di + gn], xbc[..., di + gn:]
+    with obs.span("mamba.ssd"):
+        x_heads, xh, a_log, Bh, Ch = _prepare_ssd(xs, B, C, dt, p, cfg)
+        out = ops.ssd(xh, a_log, Bh, Ch, chunk=cfg.ssm_chunk,
+                      use_pallas=use_pallas, return_state=return_state)
+        y, state = out if return_state else (out, None)
+        y = _skip(y, x_heads, p)
+    with obs.span("mamba.out"):
+        y = _gated_norm(p, y, z, cfg) @ p["out_proj"].astype(dtype)
+    if return_state:
+        return y, {"state": state, "conv": tail}
+    return y
 
 
 def init_ssm_cache(cfg: ModelConfig, batch: int, dtype=jnp.float32):
@@ -113,7 +140,6 @@ def apply_mamba_decode(p, x: jnp.ndarray, cfg: ModelConfig, cache
                        ) -> Tuple[jnp.ndarray, dict]:
     """One-token recurrence. x: (B, 1, d)."""
     dtype = x.dtype
-    bsz = x.shape[0]
     di = cfg.d_inner
     gn = cfg.ssm_groups * cfg.ssm_state
     zxbcdt = x @ p["in_proj"].astype(dtype)
@@ -122,7 +148,7 @@ def apply_mamba_decode(p, x: jnp.ndarray, cfg: ModelConfig, cache
     xbc, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"],
                                  tail=cache["conv"])
     xs, B, C = xbc[..., :di], xbc[..., di:di + gn], xbc[..., di + gn:]
-    xh, a_log, Bh, Ch = _prepare_ssd(xs, B, C, dt, p, cfg)
+    x_heads, xh, a_log, Bh, Ch = _prepare_ssd(xs, B, C, dt, p, cfg)
     # exact one-step recurrence: h' = exp(a) h + x (x) B ; y = h' C
     a = jnp.exp(a_log[:, 0].astype(jnp.float32))[:, :, None, None]
     state = cache["state"]
@@ -130,8 +156,7 @@ def apply_mamba_decode(p, x: jnp.ndarray, cfg: ModelConfig, cache
                      Bh[:, 0].astype(jnp.float32))
     state = a * state + upd
     y = jnp.einsum("bhpn,bhn->bhp", state, Ch[:, 0].astype(jnp.float32))
-    y = y.astype(dtype)[:, None]                                # (B,1,H,P)
-    y = y + xh * p["d_skip"].astype(dtype)[None, None, :, None]
-    y = y.reshape(bsz, 1, di)
-    y = apply_rmsnorm(p["norm"], y * jax.nn.silu(z), cfg.norm_eps)
-    return y @ p["out_proj"].astype(dtype), {"state": state, "conv": new_conv}
+    y = _skip(y.astype(dtype)[:, None], x_heads, p)             # (B,1,H,P)
+    with obs.span("mamba.out"):
+        y = _gated_norm(p, y, z, cfg) @ p["out_proj"].astype(dtype)
+    return y, {"state": state, "conv": new_conv}

@@ -54,17 +54,21 @@ def decode_step(params, token, cfg: ModelConfig, caches, cache_index,
 
 
 def prefill(params, batch: dict, cfg: ModelConfig, shard_fn=lambda x, n: x,
-            use_pallas: Optional[bool] = None):
+            use_pallas: Optional[bool] = None, max_len: Optional[int] = None):
+    """Returns (logits of the last position (B, 1, V), aux, caches);
+    ``max_len`` sizes the KV caches a patterned stack returns (see
+    :func:`repro.models.transformer.prefill`). encdec returns the encoder
+    memory in place of caches."""
     if cfg.family == "encdec":
         memory = encdec.encode(params, batch["frames"], cfg, shard_fn,
                                use_pallas)
         logits = encdec.decode_train(params, batch["tokens"], memory, cfg,
                                      shard_fn, use_pallas)
-        return logits, jnp.zeros((), jnp.float32), memory
+        return logits[:, -1:], jnp.zeros((), jnp.float32), memory
     prefix = batch.get("patches")
     return transformer.prefill(params, batch["tokens"], cfg,
                                prefix_embeds=prefix, shard_fn=shard_fn,
-                               use_pallas=use_pallas)
+                               use_pallas=use_pallas, max_len=max_len)
 
 
 def param_count(cfg: ModelConfig) -> int:

@@ -25,6 +25,11 @@ there in the same PR):
     per path via ``warnings.warn``).
 ``kernel.launch``
     Pallas kernel launches funneled through the dispatch GEMM executor.
+``kernel.ssd_scan`` / ``kernel.flash_attention``
+    Model calls that :mod:`repro.kernels.ops` sends to the Pallas SSD
+    scan or flash-attention kernel (not to the jnp oracle). Counted at
+    trace time: one per call site traced into a program, not per
+    execution (a scanned stack traces one period of its layers).
 ``collective.hops`` / ``collective.bytes``
     Ring-broadcast ppermute hops and on-wire bytes (counted at trace
     time: a jit-cached SUMMA call re-runs the collective without
@@ -46,6 +51,8 @@ KNOWN_COUNTERS = (
     "registry.missing_fallback",
     "registry.corrupt_fallback",
     "kernel.launch",
+    "kernel.ssd_scan",
+    "kernel.flash_attention",
     "collective.hops",
     "collective.bytes",
 )

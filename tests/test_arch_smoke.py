@@ -52,7 +52,8 @@ def test_smoke_forward_and_train_step(arch):
 
 @pytest.mark.parametrize("arch", ["minitron-8b", "mamba2-130m",
                                   "hymba-1.5b", "whisper-small",
-                                  "qwen3-moe-235b-a22b"])
+                                  "qwen3-moe-235b-a22b",
+                                  "granite-4.0-h-micro"])
 def test_smoke_decode_step(arch):
     cfg = _reduced(arch)
     key = jax.random.PRNGKey(0)
@@ -84,6 +85,7 @@ def test_full_configs_match_assignment():
         "internvl2-1b": (24, 896, 14, 2, 4864, 151655),
         "qwen3-moe-235b-a22b": (94, 4096, 64, 4, 1536, 151936),
         "kimi-k2-1t-a32b": (61, 7168, 64, 8, 2048, 163840),
+        "granite-4.0-h-micro": (40, 2048, 32, 8, 8192, 100352),
     }
     for arch, (L, d, h, kv, ff, v) in spec.items():
         cfg = registry.get_config(arch)
@@ -106,7 +108,8 @@ def test_param_counts_in_family_range():
               "mamba2-130m": (0.11e9, 0.15e9),
               "hymba-1.5b": (1.3e9, 1.9e9), "internvl2-1b": (0.4e9, 0.6e9),
               "qwen3-moe-235b-a22b": (225e9, 245e9),
-              "kimi-k2-1t-a32b": (0.95e12, 1.1e12)}
+              "kimi-k2-1t-a32b": (0.95e12, 1.1e12),
+              "granite-4.0-h-micro": (3.1e9, 3.3e9)}
     for arch, (lo, hi) in expect.items():
         n = zoo.param_count(registry.get_config(arch))
         assert lo <= n <= hi, (arch, n)
@@ -119,8 +122,8 @@ def test_param_counts_in_family_range():
 
 def test_cell_skips_documented():
     defined, skipped = registry.all_cells()
-    assert len(defined) == 32
-    assert len(skipped) == 8
+    assert len(defined) == 35
+    assert len(skipped) == 9
     assert all(s[1] == "long_500k" for s in skipped)
     # only the sub-quadratic archs run long_500k
     long_archs = {a for a, s in defined if s == "long_500k"}

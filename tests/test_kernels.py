@@ -104,6 +104,50 @@ def test_ssd_kernel_sweep(rng, L, chunk, dtype):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-4)
 
 
+@pytest.mark.parametrize("L,chunk", [(64, 16), (100, 32), (256, 64),
+                                     (200, 128)])
+def test_ssd_kernel_final_state_matches_oracle(rng, L, chunk):
+    """The kernel's final (P, N) state per (batch, head), what a prefill
+    hands to decode, against the recurrence's; L = 100 and 200 are padded
+    to whole chunks (padding decays by 1 and adds nothing)."""
+    b, h, p, n = 2, 3, 16, 8
+    x = jnp.asarray(rng.normal(size=(b, h, L, p)).astype(np.float32)) * 0.5
+    a = -jnp.abs(jnp.asarray(rng.normal(size=(b, h, L)).astype(np.float32))) * 0.1
+    B = jnp.asarray(rng.normal(size=(b, h, L, n)).astype(np.float32)) * 0.5
+    C = jnp.asarray(rng.normal(size=(b, h, L, n)).astype(np.float32)) * 0.5
+    y, state = ssd_scan(x, a, B, C, chunk=chunk, interpret=True,
+                        return_state=True)
+    tr = lambda t: jnp.moveaxis(t, 1, 2)
+    want_y, want_state = ref.ssd(tr(x), tr(a), tr(B), tr(C),
+                                 return_state=True)
+    assert state.shape == (b, h, p, n) and state.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(state), np.asarray(want_state),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(tr(want_y)),
+                               atol=5e-4)
+    # the model-layout wrapper hands back the same state, kernel or oracle
+    for use_pallas in (True, False):
+        _, got = ops.ssd(tr(x), tr(a), tr(B), tr(C), chunk=chunk,
+                         use_pallas=use_pallas, interpret=True,
+                         return_state=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want_state),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_kernel_counters_count_traced_kernel_calls(rng):
+    from repro.obs import counters
+    x = jnp.asarray(rng.normal(size=(1, 16, 2, 8)).astype(np.float32))
+    a = -jnp.abs(jnp.asarray(rng.normal(size=(1, 16, 2)).astype(np.float32)))
+    q = jnp.asarray(rng.normal(size=(1, 2, 16, 8)).astype(np.float32))
+    before = counters.snapshot()
+    ops.ssd(x, a, x, x, chunk=8, use_pallas=True, interpret=True)
+    ops.attention(q, q, q, use_pallas=True, interpret=True)
+    ops.ssd(x, a, x, x, chunk=8, use_pallas=False)
+    ops.attention(q, q, q, use_pallas=False)
+    assert counters.delta(before) == {"kernel.ssd_scan": 1,
+                                      "kernel.flash_attention": 1}
+
+
 def test_ssd_chunk_invariance(rng):
     """Chunk size must not change the math (fig.-1 eq. of SSD)."""
     b, h, L, p, n = 1, 2, 96, 8, 4

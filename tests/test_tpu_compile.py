@@ -130,3 +130,34 @@ def test_ssd_scan_compiles_at_mamba2_130m_widths(one_chip):
                                                interpret=False),
         shape(b, h, seq, p), shape(b, h, seq, dt=jnp.float32),
         shape(b, h, seq, n), shape(b, h, seq, n)))
+
+
+def test_ssd_scan_with_final_state_compiles_at_granite_widths(one_chip):
+    """granite-4.0-h-micro's prefill of 32768 tokens: 64 heads x P 64 x N
+    128, chunk 256, the final state handed to decode."""
+    b, h, seq, p, n = 1, 64, 32768, 64, 128
+
+    def shape(*dims, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    compiled = _compile(
+        lambda x, a, bm, cm: ssd_scan.ssd_scan(x, a, bm, cm, chunk=256,
+                                               interpret=False,
+                                               return_state=True),
+        shape(b, h, seq, p), shape(b, h, seq, dt=jnp.float32),
+        shape(b, h, seq, n), shape(b, h, seq, n))
+    _assert_kernel(compiled)
+    state = compiled.out_info[1]
+    assert state.shape == (b, h, p, n) and state.dtype == jnp.float32
+
+
+def test_flash_attention_compiles_at_granite_widths(one_chip):
+    """GQA 32 query / 8 KV heads, head_dim 64, 32768 positions, the
+    softmax scale 1/64 (granite's attention_multiplier)."""
+    q = jax.ShapeDtypeStruct((1, 32, 32768, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8, 32768, 64), jnp.bfloat16,
+                              sharding=one_chip)
+    _assert_kernel(_compile(
+        lambda q_, k_, v_: flash_attention.attention(
+            q_, k_, v_, scale=1 / 64, interpret=False), q, kv, kv))

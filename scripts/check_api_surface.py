@@ -131,7 +131,7 @@ EXPECTED_COUNTERS = {
     "dispatch.resolve", "dispatch.registry_hit", "dispatch.registry_miss",
     "registry.load", "registry.missing_fallback", "registry.corrupt_fallback",
     "kernel.launch", "collective.hops", "collective.bytes",
-    "kernel.ssd_scan", "kernel.flash_attention",
+    "kernel.ssd_scan", "kernel.flash_attention", "trsm.inverted_blocks",
 }
 
 # the repro.analysis static-verification surface (docs/static_analysis.md):

@@ -19,9 +19,12 @@ from __future__ import annotations
 
 from typing import Optional
 
+import numpy as np
+
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs as _obs
 from repro.blas._deprecated import warn_once
 
 
@@ -159,10 +162,13 @@ def trsm(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
          registry=None) -> jnp.ndarray:
     """Solve op(T) X = B (left=True) or X op(T) = B, T triangular, blocked.
 
-    Diagonal blocks use the sequential substitution scan (the serial
-    divider chain); off-diagonal updates are GEMMs - the paper's
-    panel/trailing structure in miniature - and follow the policy onto the
-    Pallas path.
+    With more than one block, every diagonal block is inverted at once
+    (one batched recursive halving, LAPACK's recursive dtrtri: one
+    reciprocal per diagonal element, then log2(block) levels of batched
+    products), and each block is then solved by one product with its
+    inverse. Off-diagonal updates are GEMMs - the paper's panel/trailing
+    structure in miniature - and follow the policy onto the Pallas path.
+    A single block (``n <= block``) runs the row-by-row substitution scan.
 
     Parameters
     ----------
@@ -175,8 +181,9 @@ def trsm(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
         historical default - else the ``plan_trsm`` model or the
         registry's measured width).
     policy : {"reference", "model", "tuned"}, optional
-        Applies to the off-diagonal GEMM updates (the substitution scan
-        itself has no kernel form); ``use_kernel`` deprecated alias.
+        Applies to the off-diagonal GEMM updates (the diagonal blocks'
+        inversion and products, and the substitution scan, have no kernel
+        form); ``use_kernel`` deprecated alias.
 
     Returns
     -------
@@ -207,6 +214,7 @@ def trsm(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
         pol = resolve_policy(policy, use_kernel)
     if n <= block:
         return _trsm_unblocked(a, b, lower=lower, unit_diag=unit_diag)
+    inv = _inverted_diagonal_blocks(a, block, lower, unit_diag)
     blocks = list(range(0, n, block))
     x = jnp.zeros_like(b)
     order = blocks if lower else blocks[::-1]
@@ -219,10 +227,50 @@ def trsm(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
         elif not lower and i1 < n:
             rhs = rhs - gemm(a[i0:i1, i1:], x[i1:], policy=pol,
                              interpret=interpret, registry=registry)
-        xi = _trsm_unblocked(a[i0:i1, i0:i1], rhs, lower=lower,
-                             unit_diag=unit_diag)
-        x = x.at[i0:i1].set(xi)
+        x = x.at[i0:i1].set(inv[i0 // block, :i1 - i0, :i1 - i0] @ rhs)
     return x
+
+
+def _inverted_diagonal_blocks(a: jnp.ndarray, block: int, lower: bool,
+                              unit_diag: bool) -> jnp.ndarray:
+    """Inverses of all diagonal ``block`` x ``block`` blocks of triangular
+    ``a``, as one (nblk, p, p) stack, p = block rounded up to a power of 2.
+
+    Block k's inverse is ``[k, :w, :w]`` (w its width); a last partial
+    block and the rows and columns from w to p are padded with the
+    identity, whose inverse they keep. Recursive halving as in LAPACK's
+    dtrtri, over the whole stack at once: with X the inverse of the
+    s x s diagonal sub-blocks and E the off-diagonal s x s quadrants of
+    the 2s x 2s ones, the 2s x 2s inverse is X - X E X (for lower T the
+    quadrant -D^-1 C A^-1 of inv([[A, 0], [C, D]])), since (X E)^2 = 0.
+    Only the strict triangle named by ``lower`` and, unless
+    ``unit_diag``, the diagonal are read.
+    """
+    p = 1 << (block - 1).bit_length()
+    t = jnp.stack([_pad_identity(a[i:i + block, i:i + block], p)
+                   for i in range(0, a.shape[0], block)])
+    _obs.inc("trsm.inverted_blocks", t.shape[0])
+    eye = jnp.eye(p, dtype=t.dtype)
+    x = jnp.broadcast_to(eye, t.shape) if unit_diag else \
+        eye / jnp.diagonal(t, axis1=-2, axis2=-1)[..., None, :]
+    i, j = np.indices((p, p))
+    s = 1
+    while s < p:
+        quadrant = (i // s > j // s) if lower else (i // s < j // s)
+        e = jnp.where(quadrant & (i // (2 * s) == j // (2 * s)), t, 0)
+        x = x - x @ (e @ x)
+        s *= 2
+    return x
+
+
+def _pad_identity(t: jnp.ndarray, size: int) -> jnp.ndarray:
+    """Pad square ``t`` to ``size`` x ``size``, with the identity on the
+    new part of the diagonal."""
+    w = t.shape[0]
+    if w == size:
+        return t
+    return jnp.pad(t, (0, size - w)) + jnp.diag(
+        (jnp.arange(size) >= w).astype(t.dtype))
 
 
 def _trsm_unblocked(a: jnp.ndarray, b: jnp.ndarray, lower: bool,

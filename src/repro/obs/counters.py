@@ -30,6 +30,10 @@ there in the same PR):
     scan or flash-attention kernel (not to the jnp oracle). Counted at
     trace time: one per call site traced into a program, not per
     execution (a scanned stack traces one period of its layers).
+``trsm.inverted_blocks``
+    Diagonal blocks that :func:`repro.blas.level3.trsm`'s blocked path
+    inverts (all of a call's at once, as one batched stack). Counted at
+    trace time: a call's block count each time it is traced.
 ``collective.hops`` / ``collective.bytes``
     Ring-broadcast ppermute hops and on-wire bytes (counted at trace
     time: a jit-cached SUMMA call re-runs the collective without
@@ -53,6 +57,7 @@ KNOWN_COUNTERS = (
     "kernel.launch",
     "kernel.ssd_scan",
     "kernel.flash_attention",
+    "trsm.inverted_blocks",
     "collective.hops",
     "collective.bytes",
 )

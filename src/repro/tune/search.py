@@ -294,9 +294,10 @@ def tune_trsm(n: int, nrhs: int = 8, dtype=jnp.float32,
               machine: Optional[MachineSpec] = None) -> SweepResult:
     """Sweep the blocked-TRSM diagonal width; record the measured winner.
 
-    Measured on the reference inner-GEMM path (the block trade-off - serial
-    substitution vs trailing update - is the same on both paths, and the
-    interpret-mode kernel would drown it in emulation overhead on CPU).
+    Measured on the reference inner-GEMM path (the block trade-off - the
+    diagonal blocks' inversion vs the off-diagonal updates - is the same on
+    both paths, and the interpret-mode kernel would drown it in emulation
+    overhead on CPU).
     """
     from repro.blas import level3                   # lazy: avoid import cycle
     mach = _mach(machine)

@@ -5,12 +5,15 @@ comparisons go through the shared dtype-keyed tolerance helper in
 conftest.py (oracle computed in float64). This is the testing convention
 ROADMAP.md prescribes for every new numeric routine.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 import scipy.linalg
 
 from repro import blas
+from repro.blas import level3
+from repro.obs import counters
 
 DTYPES = [np.float32, jnp.bfloat16]
 SHAPES_MM = [(8, 8, 8), (24, 36, 12), (17, 5, 29), (1, 64, 1)]
@@ -156,6 +159,46 @@ def test_dtrsm_grid_vs_scipy(rng, assert_close, n, nrhs, block, lower, left,
         ref = scipy.linalg.solve_triangular(_f64(t).T, _f64(b).T,
                                             lower=not lower).T
     assert_close(got, ref, scale=4.0)
+
+
+@pytest.mark.parametrize("left", [True, False])
+@pytest.mark.parametrize("lower,unit_diag", [(True, True), (False, False),
+                                             (True, False), (False, True)])
+@pytest.mark.parametrize("n,nrhs", [(200, 5), (256, 1), (256, 37)])
+def test_trsm_inverted_blocks_vs_scipy(rng, assert_close, n, nrhs, lower,
+                                       unit_diag, left):
+    """The blocked path (n > block 64, a partial last block at n = 200)
+    on triangles of scipy's LU of an N(0, 1) matrix: L and U as getrs
+    solves them (unit lower, non-dominant upper), and their transposes for
+    the other two (UPLO, DIAG) pairs. The whole packed array is passed, so
+    only the named triangle and, unless unit, the diagonal may be read."""
+    lu = scipy.linalg.lu_factor(rng.normal(size=(n, n)))[0]
+    packed = (lu if lower == unit_diag else lu.T).astype(np.float32)
+    t = (np.tril(packed) if lower else np.triu(packed)).astype(np.float64)
+    if unit_diag:
+        np.fill_diagonal(t, 1.0)
+    b = _mk(rng, (n, nrhs) if left else (nrhs, n), np.float32)
+    got = level3.trsm(jnp.asarray(packed), b, lower=lower,
+                      unit_diag=unit_diag, left=left, block=64)
+    if left:
+        ref = scipy.linalg.solve_triangular(t, _f64(b), lower=lower)
+    else:  # X T = B
+        ref = scipy.linalg.solve_triangular(t.T, _f64(b).T,
+                                            lower=not lower).T
+    assert_close(got, ref, scale=4.0)
+
+
+def test_trsm_blocked_path_has_no_loop(rng):
+    """n = 512 at block 64: the eight diagonal blocks are inverted as one
+    stack, so the compiled solve holds no while loop."""
+    t = jnp.asarray(np.tril(rng.normal(size=(512, 512))) + 16 * np.eye(512),
+                    jnp.float32)
+    b = _mk(rng, (512, 3), np.float32)
+    before = counters.value("trsm.inverted_blocks")
+    hlo = jax.jit(lambda t, b: level3.trsm(t, b, block=64)).lower(
+        t, b).compile().as_text()
+    assert counters.value("trsm.inverted_blocks") - before == 8
+    assert "while" not in hlo
 
 
 # ------------------ dtype-generic repro.linalg front-end --------------------

@@ -350,6 +350,8 @@ def test_linalg_grid_policy_mesh_dtype():
                     close(got, want_mm, scale=8.0, msg="gemm " + tag)
                     close(linalg.trsm(t, rhs, lower=True), want_tr,
                           scale=16.0, msg="trsm " + tag)
+                    close(linalg.trsm(t, rhs, lower=True, block=8), want_tr,
+                          scale=16.0, msg="blocked trsm " + tag)
                     res = linalg.batched_cholesky(spd, block=8)
                     close(res.factors, want_l, scale=64.0,
                           msg="cholesky " + tag)

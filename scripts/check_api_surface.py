@@ -58,7 +58,7 @@ EXPECTED_PARAMS = {
 }
 
 EXPECTED_CONTEXT_FIELDS = {"policy", "mesh", "registry", "accum_dtype",
-                           "interpret", "machine", "obs"}
+                           "machine", "obs"}
 
 EXPECTED_ARCH_ALL = [
     # spec types

@@ -198,16 +198,6 @@ with tempfile.TemporaryDirectory() as d:
 print("calibration smoke OK: fit + register + JSON round-trip + residuals")
 PY
 
-echo "== deprecation shims (DeprecationWarning -> error, our module only) =="
-# the module's pytestmark escalates DeprecationWarning to error for every
-# test in it (the shim warnings attribute to the caller, i.e. that module,
-# via stacklevel), so an unexpected deprecation path in repro.* fails;
-# the -W flag additionally escalates warnings attributed to the module at
-# collection/import time (note: no "tests." prefix - tests/ is not a
-# package, so the module __name__ is bare)
-python -m pytest -q tests/test_linalg_deprecation.py \
-    -W "error::DeprecationWarning:test_linalg_deprecation"
-
 echo "== docs reference check (stale paths must fail) =="
 python - <<'PY'
 import os, re, sys

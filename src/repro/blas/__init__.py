@@ -1,7 +1,6 @@
 from repro.blas import level1, level2, level3
-from repro.blas.level1 import (asum, axpy, dasum, daxpy, ddot, dnrm2, dot,
-                               drot, dscal, iamax, idamax, nrm2, rot, scal)
-from repro.blas.level2 import dgemv, dger, dtrsv, gemv, ger, trsv
-from repro.blas.level3 import dgemm, dsyrk, dtrsm, gemm, syrk, trsm
+from repro.blas.level1 import asum, axpy, dot, iamax, nrm2, rot, scal
+from repro.blas.level2 import gemv, ger, trsv
+from repro.blas.level3 import gemm, syrk, trsm
 from repro.blas import distributed
 from repro.blas.distributed import make_blas_mesh, mesh_key, pdgemm, pdtrsm

@@ -69,17 +69,16 @@ def _pad2(a: jnp.ndarray, r0: int, r1: int) -> jnp.ndarray:
     return jnp.pad(a, ((0, p0), (0, p1)))
 
 
-def _local_update(ap, bp, res, interpret: Optional[bool]):
+def _local_update(ap, bp, res):
     """One SUMMA panel update on the resolved path (jnp or Pallas) - the
     exact executor every other policy-dispatched GEMM uses."""
     from repro.tune.dispatch import _gemm_exec      # lazy: avoid cycle
-    return _gemm_exec(ap, bp, res, interpret)
+    return _gemm_exec(ap, bp, res)
 
 
 def pdgemm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh,
            c: Optional[jnp.ndarray] = None, alpha=1.0, beta=0.0,
-           policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-           interpret: Optional[bool] = None, registry=None) -> jnp.ndarray:
+           policy: Optional[str] = None, registry=None) -> jnp.ndarray:
     """C <- alpha * A B + beta * C, SUMMA-sharded over a ("x", "y") mesh.
 
     Parameters
@@ -99,8 +98,7 @@ def pdgemm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh,
         Per-step local updates run plain jnp (``reference``) or the Pallas
         MXU kernel at the config ``resolve("pdgemm", (m, n, k), ...,
         mesh=(px, py))`` picks - ``tuned`` reads the mesh-keyed registry
-        entry and cold-starts to ``model``. ``use_kernel`` stays the
-        deprecated boolean alias.
+        entry and cold-starts to ``model``.
 
     Returns
     -------
@@ -120,8 +118,7 @@ def pdgemm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh,
     assert k == k2, (a.shape, b.shape)
     steps = px * py
     res = _tune.resolve("pdgemm", (m, n, k), a.dtype, policy=policy,
-                        use_kernel=use_kernel, registry=registry,
-                        mesh=(px, py))
+                        registry=registry, mesh=(px, py))
     # pad so rows/cols tile the mesh and K splits into px*py equal fine
     # panels (k <= steps * kf, so K always pads to exactly steps * kf)
     kf = -(-max(k, 1) // steps)
@@ -135,8 +132,7 @@ def pdgemm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh,
         info={"m": m, "n": n, "k": k, "px": px, "py": py, "kf": kf,
               "itemsize": jnp.dtype(a.dtype).itemsize,
               "dtype": jnp.dtype(a.dtype).name}))
-    inner = functools.partial(_summa_inner, px=px, py=py, kf=kf, res=res,
-                              interpret=interpret)
+    inner = functools.partial(_summa_inner, px=px, py=py, kf=kf, res=res)
     f = jax.shard_map(inner, mesh=mesh,
                       in_specs=(P("x", "y"), P("x", "y")),
                       out_specs=P("x", "y"), check_vma=False)
@@ -147,8 +143,7 @@ def pdgemm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh,
     return out
 
 
-def _summa_inner(a, b, *, px: int, py: int, kf: int, res,
-                 interpret: Optional[bool]):
+def _summa_inner(a, b, *, px: int, py: int, kf: int, res):
     """Per-device SUMMA body: a (m/px, k/py) A shard holding coarse k-panel
     ``j``; b (k/px, n/py) B shard holding coarse k-panel ``i``. Fine panel
     ``g`` lives at A coarse ``g // px`` offset ``(g % px) * kf`` and B
@@ -159,14 +154,13 @@ def _summa_inner(a, b, *, px: int, py: int, kf: int, res,
         b_own, b_off = g // py, (g % py) * kf
         ap = ring_bcast(a[:, a_off:a_off + kf], "y", py, a_own)
         bp = ring_bcast(b[b_off:b_off + kf, :], "x", px, b_own)
-        acc = acc + _local_update(ap, bp, res, interpret)
+        acc = acc + _local_update(ap, bp, res)
     return acc
 
 
 def pdtrsm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh, lower: bool = True,
            unit_diag: bool = False, left: bool = True,
            block: Optional[int] = None, policy: Optional[str] = None,
-           use_kernel: Optional[bool] = None, interpret: Optional[bool] = None,
            registry=None) -> jnp.ndarray:
     """Solve op(T) X = B with the right-hand sides sharded over the mesh.
 
@@ -195,7 +189,6 @@ def pdtrsm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh, lower: bool = True,
     if not left:
         return pdtrsm(a.T, b.T, mesh, lower=not lower, unit_diag=unit_diag,
                       left=True, block=block, policy=policy,
-                      use_kernel=use_kernel, interpret=interpret,
                       registry=registry).T
     from repro.blas.level3 import trsm
     px, py = _mesh_xy(mesh)
@@ -207,8 +200,7 @@ def pdtrsm(a: jnp.ndarray, b: jnp.ndarray, mesh: Mesh, lower: bool = True,
 
     def inner(t, r):
         return trsm(t, r, lower=lower, unit_diag=unit_diag, left=True,
-                    block=block, policy=policy, use_kernel=use_kernel,
-                    interpret=interpret, registry=registry)
+                    block=block, policy=policy, registry=registry)
 
     f = jax.shard_map(inner, mesh=mesh,
                       in_specs=(P(None, None), P(None, ("x", "y"))),

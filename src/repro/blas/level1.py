@@ -1,12 +1,11 @@
 """Level-1 BLAS in JAX (the paper's section-4.1 workloads).
 
-dtype-generic cores under their un-prefixed names (``dot``, ``axpy``, ...);
-``ddot``/``daxpy``/... survive as deprecation shims that forward through
-:mod:`repro.linalg`. ``dot`` exposes the *schedule* knob the paper's
-analysis is about: tree / sequential / strided-U reductions produce
-identical values (up to FP reassociation) with very different dependence
-structure; the strided form with U = ``codesign.optimal_accumulators`` is
-the TPU-codesign schedule.
+dtype-generic cores under their un-prefixed names (``dot``, ``axpy``, ...),
+behind the :mod:`repro.linalg` front end. ``dot`` exposes the *schedule*
+knob the paper's analysis is about: tree / sequential / strided-U
+reductions produce identical values (up to FP reassociation) with very
+different dependence structure; the strided form with
+U = ``codesign.optimal_accumulators`` is the TPU-codesign schedule.
 
 Level-1 routines are pure jnp (no policy - there is no kernel-shaped core
 to dispatch); the policy mechanism starts at Level 2. All routines accept
@@ -16,11 +15,8 @@ NumPy oracles in ``tests/test_differential_blas.py`` and
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 from jax import lax
-
-from repro.blas._deprecated import warn_once
 
 
 def dot(x: jnp.ndarray, y: jnp.ndarray, schedule: str = "tree",
@@ -132,64 +128,3 @@ def rot(x, y, c, s):
     Oracle: ``tests/test_differential_blas.py``.
     """
     return c * x + s * y, c * y - s * x
-
-
-# -------------------------- deprecated d-prefixed shims ----------------------
-# Thin forwards through repro.linalg under a *pinned* compat context
-# (mesh=None, accum_dtype=None), so an active context can never change a
-# deprecated call's numerics. One DeprecationWarning per routine. Oracle +
-# warning behavior: tests/test_linalg_deprecation.py.
-
-def _compat():
-    from repro.linalg.context import compat_context
-    return compat_context()
-
-
-def ddot(x, y, schedule: str = "tree", accumulators: int = 8):
-    """Deprecated alias of :func:`repro.linalg.dot`."""
-    warn_once("ddot", "dot")
-    from repro import linalg
-    return linalg.dot(x, y, schedule=schedule, accumulators=accumulators,
-                      context=_compat())
-
-
-def daxpy(alpha, x, y):
-    """Deprecated alias of :func:`repro.linalg.axpy`."""
-    warn_once("daxpy", "axpy")
-    from repro import linalg
-    return linalg.axpy(alpha, x, y, context=_compat())
-
-
-def dscal(alpha, x):
-    """Deprecated alias of :func:`repro.linalg.scal`."""
-    warn_once("dscal", "scal")
-    from repro import linalg
-    return linalg.scal(alpha, x, context=_compat())
-
-
-def dnrm2(x):
-    """Deprecated alias of :func:`repro.linalg.nrm2`."""
-    warn_once("dnrm2", "nrm2")
-    from repro import linalg
-    return linalg.nrm2(x, context=_compat())
-
-
-def dasum(x):
-    """Deprecated alias of :func:`repro.linalg.asum`."""
-    warn_once("dasum", "asum")
-    from repro import linalg
-    return linalg.asum(x, context=_compat())
-
-
-def idamax(x):
-    """Deprecated alias of :func:`repro.linalg.iamax`."""
-    warn_once("idamax", "iamax")
-    from repro import linalg
-    return linalg.iamax(x, context=_compat())
-
-
-def drot(x, y, c, s):
-    """Deprecated alias of :func:`repro.linalg.rot`."""
-    warn_once("drot", "rot")
-    from repro import linalg
-    return linalg.rot(x, y, c, s, context=_compat())

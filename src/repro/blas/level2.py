@@ -1,8 +1,7 @@
 """Level-2 BLAS in JAX.
 
-Cores under un-prefixed names; ``dgemv``/``dger``/``dtrsv`` are
-deprecation shims forwarding through :mod:`repro.linalg`. ``gemv`` shares
-the BLAS-3 policy mechanism: its matvec core resolves through
+The numeric cores behind :mod:`repro.linalg`. ``gemv`` shares the BLAS-3
+policy mechanism: its matvec core resolves through
 :mod:`repro.tune.dispatch` (``reference`` = plain jnp; ``model`` /
 ``tuned`` route op(A) x through the Pallas GEMM kernel as an (m, n) x
 (n, 1) product), so Level-2 configs live in the same registry.
@@ -14,12 +13,9 @@ from typing import Optional
 import jax.numpy as jnp
 from jax import lax
 
-from repro.blas._deprecated import warn_once
-
 
 def gemv(a: jnp.ndarray, x: jnp.ndarray, beta=0.0, y=None,
          alpha=1.0, trans: bool = False, policy: Optional[str] = None,
-         use_kernel: Optional[bool] = None, interpret: Optional[bool] = None,
          registry=None) -> jnp.ndarray:
     """y <- alpha*op(A) x + beta*y (BLAS GEMV core).
 
@@ -33,8 +29,7 @@ def gemv(a: jnp.ndarray, x: jnp.ndarray, beta=0.0, y=None,
     policy : {"reference", "model", "tuned"}, optional
         ``reference`` is plain jnp; ``model``/``tuned`` run op(A) x
         through the Pallas GEMM kernel as an (m, n) x (n, 1) product, so
-        Level-2 configs share the gemm registry entries. ``use_kernel``
-        is the deprecated boolean alias (True == "model").
+        Level-2 configs share the gemm registry entries.
 
     Returns
     -------
@@ -49,7 +44,6 @@ def gemv(a: jnp.ndarray, x: jnp.ndarray, beta=0.0, y=None,
     """
     from repro.tune import dispatch as _tune
     ax = _tune.dispatch("gemv", a, x, trans=trans, policy=policy,
-                        use_kernel=use_kernel, interpret=interpret,
                         registry=registry)
     out = alpha * ax
     if y is not None:
@@ -111,37 +105,3 @@ def trsv(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
     x0 = jnp.zeros_like(b)
     x, _ = lax.scan(body, x0, order)
     return x
-
-
-# -------------------------- deprecated d-prefixed shims ----------------------
-
-def dgemv(a, x, beta=0.0, y=None, alpha=1.0, trans: bool = False,
-          policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-          interpret: Optional[bool] = None, registry=None,
-          use_pallas: Optional[bool] = None):
-    """Deprecated alias of :func:`repro.linalg.gemv` (old kwargs mapped to
-    a per-call context). Warning + bitwise-identity oracle:
-    ``tests/test_linalg_deprecation.py``."""
-    warn_once("dgemv", "gemv")
-    from repro import linalg
-    from repro.linalg.context import compat_context
-    return linalg.gemv(a, x, y=y, alpha=alpha, beta=beta, trans=trans,
-                       context=compat_context(policy, use_kernel, interpret,
-                                              registry, use_pallas))
-
-
-def dger(alpha, x, y, a):
-    """Deprecated alias of :func:`repro.linalg.ger`."""
-    warn_once("dger", "ger")
-    from repro import linalg
-    from repro.linalg.context import compat_context
-    return linalg.ger(alpha, x, y, a, context=compat_context())
-
-
-def dtrsv(a, b, lower: bool = True, unit_diag: bool = False):
-    """Deprecated alias of :func:`repro.linalg.trsv`."""
-    warn_once("dtrsv", "trsv")
-    from repro import linalg
-    from repro.linalg.context import compat_context
-    return linalg.trsv(a, b, lower=lower, unit_diag=unit_diag,
-                       context=compat_context())

@@ -10,10 +10,9 @@ GEMMs, so a blocked factorization dispatches *every* trailing flop onto
 the one hot path.
 
 These are the numeric cores; the public, context-scoped front-end is
-:mod:`repro.linalg`. The old d-prefixed names (``dgemm``/``dsyrk``/
-``dtrsm``) are deprecation shims forwarding there, and
-``use_kernel=True/False`` remains a deprecated alias for
-``policy="model"`` / ``"reference"``.
+:mod:`repro.linalg`, which resolves its context into their ``policy`` and
+``registry`` arguments. The Pallas kernels take interpret mode from the
+device.
 """
 from __future__ import annotations
 
@@ -25,13 +24,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro import obs as _obs
-from repro.blas._deprecated import warn_once
 
 
 def gemm(a: jnp.ndarray, b: jnp.ndarray, c: Optional[jnp.ndarray] = None,
          alpha=1.0, beta=0.0, transa: bool = False, transb: bool = False,
-         policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-         interpret: Optional[bool] = None, registry=None) -> jnp.ndarray:
+         policy: Optional[str] = None, registry=None) -> jnp.ndarray:
     """C <- alpha * op(A) op(B) + beta * C (BLAS GEMM core).
 
     Parameters
@@ -45,10 +42,7 @@ def gemm(a: jnp.ndarray, b: jnp.ndarray, c: Optional[jnp.ndarray] = None,
         ``reference`` = plain jnp (the oracle path); ``model`` = Pallas
         MXU kernel at the :func:`repro.core.codesign.plan_gemm` tiling;
         ``tuned`` = the registry's measured config, cold-starting to
-        ``model``. ``use_kernel`` is the deprecated alias
-        (True == "model", False == "reference").
-    interpret : run Pallas in interpret mode; ``None`` takes the device's
-        mode (:func:`repro.kernels.ops.interpret_mode`).
+        ``model``.
 
     Returns
     -------
@@ -67,7 +61,6 @@ def gemm(a: jnp.ndarray, b: jnp.ndarray, c: Optional[jnp.ndarray] = None,
     op_a = a.T if transa else a
     op_b = b.T if transb else b
     ab = _tune.dispatch("gemm", op_a, op_b, policy=policy,
-                        use_kernel=use_kernel, interpret=interpret,
                         registry=registry)
     out = alpha * ab
     if c is not None:
@@ -78,8 +71,6 @@ def gemm(a: jnp.ndarray, b: jnp.ndarray, c: Optional[jnp.ndarray] = None,
 def gemm_bias_act(a: jnp.ndarray, b: jnp.ndarray,
                   bias: Optional[jnp.ndarray] = None, epilogue: str = "none",
                   policy: Optional[str] = None,
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
                   registry=None) -> jnp.ndarray:
     """C = act(A @ B + bias) - the fused-epilogue GEMM core.
 
@@ -105,14 +96,12 @@ def gemm_bias_act(a: jnp.ndarray, b: jnp.ndarray,
     from repro.tune import dispatch as _tune
     return _tune.dispatch("gemm+epilogue", a, b, bias=bias,
                           epilogue=epilogue, policy=policy,
-                          use_kernel=use_kernel, interpret=interpret,
                           registry=registry)
 
 
 def syrk(a: jnp.ndarray, c: Optional[jnp.ndarray] = None, alpha=1.0,
          beta=0.0, lower: bool = True, trans: bool = False,
-         policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-         interpret: Optional[bool] = None, registry=None) -> jnp.ndarray:
+         policy: Optional[str] = None, registry=None) -> jnp.ndarray:
     """C <- alpha op(A) op(A)^T + beta C (BLAS SYRK core), symmetric output.
 
     Parameters
@@ -124,8 +113,7 @@ def syrk(a: jnp.ndarray, c: Optional[jnp.ndarray] = None, alpha=1.0,
     policy : {"reference", "model", "tuned"}, optional
         The product runs through the same ``gemm`` kernel path (SYRK
         shares the gemm registry entries), so SYRK reaches Pallas under
-        the kernel policies; ``use_kernel`` deprecated alias as in
-        :func:`gemm`.
+        the kernel policies.
 
     Returns
     -------
@@ -139,7 +127,6 @@ def syrk(a: jnp.ndarray, c: Optional[jnp.ndarray] = None, alpha=1.0,
     """
     from repro.tune import dispatch as _tune
     full = alpha * _tune.dispatch("syrk", a, trans=trans, policy=policy,
-                                  use_kernel=use_kernel, interpret=interpret,
                                   registry=registry)
     if c is not None:
         full = full + beta * c
@@ -158,7 +145,6 @@ def mirror_triangle(full: jnp.ndarray, lower: bool) -> jnp.ndarray:
 def trsm(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
          unit_diag: bool = False, left: bool = True,
          block: Optional[int] = None, policy: Optional[str] = None,
-         use_kernel: Optional[bool] = None, interpret: Optional[bool] = None,
          registry=None) -> jnp.ndarray:
     """Solve op(T) X = B (left=True) or X op(T) = B, T triangular, blocked.
 
@@ -183,7 +169,7 @@ def trsm(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
     policy : {"reference", "model", "tuned"}, optional
         Applies to the off-diagonal GEMM updates (the diagonal blocks'
         inversion and products, and the substitution scan, have no kernel
-        form); ``use_kernel`` deprecated alias.
+        form).
 
     Returns
     -------
@@ -200,18 +186,17 @@ def trsm(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
         # X T = B  <=>  T^T X^T = B^T
         return trsm(a.T, b.T, lower=not lower, unit_diag=unit_diag,
                     left=True, block=block, policy=policy,
-                    use_kernel=use_kernel, interpret=interpret,
                     registry=registry).T
     n = a.shape[0]
     if block is None:
         from repro.tune import dispatch as _tune
         nrhs = b.shape[1] if b.ndim == 2 else 1
         res = _tune.resolve("trsm", (n, nrhs), a.dtype, policy=policy,
-                            use_kernel=use_kernel, registry=registry)
+                            registry=registry)
         pol, block = res.policy, res.block
     else:
         from repro.tune.policy import resolve_policy
-        pol = resolve_policy(policy, use_kernel)
+        pol = resolve_policy(policy)
     if n <= block:
         return _trsm_unblocked(a, b, lower=lower, unit_diag=unit_diag)
     inv = _inverted_diagonal_blocks(a, block, lower, unit_diag)
@@ -223,10 +208,10 @@ def trsm(a: jnp.ndarray, b: jnp.ndarray, lower: bool = True,
         rhs = b[i0:i1]
         if lower and i0 > 0:
             rhs = rhs - gemm(a[i0:i1, :i0], x[:i0], policy=pol,
-                             interpret=interpret, registry=registry)
+                             registry=registry)
         elif not lower and i1 < n:
             rhs = rhs - gemm(a[i0:i1, i1:], x[i1:], policy=pol,
-                             interpret=interpret, registry=registry)
+                             registry=registry)
         x = x.at[i0:i1].set(inv[i0 // block, :i1 - i0, :i1 - i0] @ rhs)
     return x
 
@@ -287,50 +272,3 @@ def _trsm_unblocked(a: jnp.ndarray, b: jnp.ndarray, lower: bool,
 
     x, _ = lax.scan(body, jnp.zeros_like(b), order)
     return x
-
-
-# -------------------------- deprecated d-prefixed shims ----------------------
-
-def dgemm(a, b, c=None, alpha=1.0, beta=0.0, transa: bool = False,
-          transb: bool = False, policy: Optional[str] = None,
-          use_kernel: Optional[bool] = None, interpret: Optional[bool] = None,
-          registry=None, use_pallas: Optional[bool] = None):
-    """Deprecated alias of :func:`repro.linalg.gemm` (old kwargs mapped to
-    a local, per-call context). Warning + bitwise-identity oracle:
-    ``tests/test_linalg_deprecation.py``."""
-    warn_once("dgemm", "gemm")
-    from repro import linalg
-    from repro.linalg.context import compat_context
-    return linalg.gemm(a, b, c=c, alpha=alpha, beta=beta, transa=transa,
-                       transb=transb,
-                       context=compat_context(policy, use_kernel, interpret,
-                                              registry, use_pallas))
-
-
-def dsyrk(a, c=None, alpha=1.0, beta=0.0, lower: bool = True,
-          trans: bool = False, policy: Optional[str] = None,
-          use_kernel: Optional[bool] = None, interpret: Optional[bool] = None,
-          registry=None, use_pallas: Optional[bool] = None):
-    """Deprecated alias of :func:`repro.linalg.syrk`."""
-    warn_once("dsyrk", "syrk")
-    from repro import linalg
-    from repro.linalg.context import compat_context
-    return linalg.syrk(a, c=c, alpha=alpha, beta=beta, lower=lower,
-                       trans=trans,
-                       context=compat_context(policy, use_kernel, interpret,
-                                              registry, use_pallas))
-
-
-def dtrsm(a, b, lower: bool = True, unit_diag: bool = False,
-          left: bool = True, block: Optional[int] = None,
-          policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-          interpret: Optional[bool] = None, registry=None,
-          use_pallas: Optional[bool] = None):
-    """Deprecated alias of :func:`repro.linalg.trsm`."""
-    warn_once("dtrsm", "trsm")
-    from repro import linalg
-    from repro.linalg.context import compat_context
-    return linalg.trsm(a, b, lower=lower, unit_diag=unit_diag, left=left,
-                       block=block,
-                       context=compat_context(policy, use_kernel, interpret,
-                                              registry, use_pallas))

@@ -27,10 +27,10 @@ def _on_tpu() -> bool:
 
 def interpret_mode() -> bool:
     """Whether Pallas kernels run in interpret mode: exactly when the
-    default backend is not a TPU. Every ``interpret=None`` default in the
-    kernels, the BLAS/LAPACK drivers and :mod:`repro.linalg`'s context
-    resolves here, so a TPU run always compiles its kernels and a CPU run
-    always interprets them."""
+    default backend is not a TPU. Every kernel's ``interpret=None``
+    default resolves here, and nothing above the kernels sets it, so a TPU
+    run always compiles its kernels and a CPU run always interprets
+    them."""
     return not _on_tpu()
 
 

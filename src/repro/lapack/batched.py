@@ -62,31 +62,25 @@ def _resolve_block(kmax: int, block: Optional[int], kind: str,
 
 def batched_potrf(a: jnp.ndarray, block: Optional[int] = None,
                   policy: Optional[str] = None,
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """Cholesky of a (B, n, n) SPD batch; factors holds L (lower).
 
     float32/float64 (NaNs per non-SPD item, LAPACK-style). ``policy``
-    threads to every trailing update via :mod:`repro.tune.dispatch`
-    (``use_kernel`` deprecated alias); ``block=None`` takes the
-    ``plan_factorization`` model pick. Oracle:
+    threads to every trailing update via :mod:`repro.tune.dispatch`;
+    ``block=None`` takes the ``plan_factorization`` model pick. Oracle:
     ``tests/test_lapack_batched.py`` (round-trip + kernel-path-identical);
     mesh-parallel form: :func:`repro.lapack.distributed.batched_potrf`.
     """
     assert a.ndim == 3 and a.shape[1] == a.shape[2], a.shape
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     nb = _resolve_block(a.shape[1], block, "potrf", a.dtype)
     f = jax.vmap(lambda x: cholesky.potrf(x, block=nb, policy=pol,
-                                          interpret=interpret,
                                           registry=registry))
     return FactorizationResult(f(a), None, None, "potrf", nb)
 
 
 def batched_getrf(a: jnp.ndarray, block: Optional[int] = None,
                   policy: Optional[str] = None,
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """LU with partial pivoting of a (B, m, n) batch.
 
@@ -97,18 +91,16 @@ def batched_getrf(a: jnp.ndarray, block: Optional[int] = None,
     :func:`repro.lapack.distributed.batched_getrf`.
     """
     assert a.ndim == 3, a.shape
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     nb = _resolve_block(min(a.shape[1], a.shape[2]), block, "getrf", a.dtype)
     f = jax.vmap(lambda x: lu.getrf(x, block=nb, policy=pol,
-                                    interpret=interpret, registry=registry))
+                                    registry=registry))
     packed, piv = f(a)
     return FactorizationResult(packed, piv, None, "getrf", nb)
 
 
 def batched_geqrf(a: jnp.ndarray, block: Optional[int] = None,
                   policy: Optional[str] = None,
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """Householder QR of a (B, m, n) batch (packed R/V + tau per item).
 
@@ -117,35 +109,32 @@ def batched_geqrf(a: jnp.ndarray, block: Optional[int] = None,
     :func:`repro.lapack.distributed.batched_geqrf`.
     """
     assert a.ndim == 3, a.shape
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     nb = _resolve_block(min(a.shape[1], a.shape[2]), block, "geqrf", a.dtype)
     f = jax.vmap(lambda x: qr.geqrf(x, block=nb, policy=pol,
-                                    interpret=interpret, registry=registry))
+                                    registry=registry))
     packed, tau = f(a)
     return FactorizationResult(packed, None, tau, "geqrf", nb)
 
 
 def batched_solve(res: FactorizationResult, b: jnp.ndarray,
                   policy: Optional[str] = None,
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
                   registry=None) -> jnp.ndarray:
     """Solve A_i x_i = b_i for every batch item from a FactorizationResult.
 
     b: (B, n) or (B, n, k). potrf solves the SPD system L L^T x = b; getrf
     the pivoted L U x = P b; geqrf the least-squares system via
-    R^{-1} Q^T b (m >= n). ``policy`` threads to every triangular solve
-    (``use_kernel`` deprecated alias). Oracle:
+    R^{-1} Q^T b (m >= n). ``policy`` threads to every triangular solve.
+    Oracle:
     ``tests/test_lapack_batched.py`` (solve residuals per kind);
     mesh-parallel form: :func:`repro.lapack.distributed.batched_solve`.
     """
     vec = b.ndim == 2
     rhs = b[:, :, None] if vec else b
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
 
     def trsm(t, r, **kw):
-        return _trsm(t, r, left=True, policy=pol, interpret=interpret,
-                     registry=registry, **kw)
+        return _trsm(t, r, left=True, policy=pol, registry=registry, **kw)
 
     if res.kind == "potrf":
         def solve1(l, r):

@@ -3,8 +3,8 @@
 Blocked right-looking form: POTRF(diag) + TRSM(panel) + SYRK(trailing).
 Every trailing flop dispatches through :mod:`repro.blas.level3`, whose
 kernel configs resolve via :mod:`repro.tune.dispatch`: ``policy="model"``
-(the deprecated ``use_kernel=True``) lowers the SYRK/GEMM hot path onto
-the Pallas MXU kernel (interpret mode on CPU); ``"tuned"`` uses the
+lowers the SYRK/GEMM hot path onto the Pallas MXU kernel (interpret mode
+on CPU); ``"tuned"`` uses the
 registry's measured config. The default panel width comes from
 :func:`repro.core.codesign.plan_factorization` - the same roofline +
 pipeline-depth model that tiles the GEMM itself. Public front-end:
@@ -66,8 +66,7 @@ def potrf_unblocked(a: jnp.ndarray) -> jnp.ndarray:
 
 
 def potrf(a: jnp.ndarray, block: Optional[int] = None,
-          policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-          interpret: Optional[bool] = None, registry=None,
+          policy: Optional[str] = None, registry=None,
           fuse: Optional[bool] = None) -> jnp.ndarray:
     """Blocked right-looking POTRF: panel = hazards, trailing = GEMM.
 
@@ -81,8 +80,7 @@ def potrf(a: jnp.ndarray, block: Optional[int] = None,
     policy : {"reference", "model", "tuned"}, optional
         Every trailing update (panel TRSM + trailing GEMM) dispatches
         through :mod:`repro.blas.level3`, so the kernel policies put all
-        trailing flops on the Pallas MXU path; ``use_kernel`` is the
-        deprecated alias (True == "model").
+        trailing flops on the Pallas MXU path.
     registry : tuned-config registry forwarded to every trailing update
         (``None`` = the process default).
     fuse : stream each trailing TRSM->SYRK pair through the fused
@@ -105,7 +103,7 @@ def potrf(a: jnp.ndarray, block: Optional[int] = None,
     """
     from repro.tune import dispatch as _tune
     from repro.tune.policy import resolve_policy
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     n = a.shape[0]
     if block is None:
         block = default_block(n, "potrf", a.dtype)
@@ -129,8 +127,7 @@ def potrf(a: jnp.ndarray, block: Optional[int] = None,
                 x, c_out = _tune.dispatch(
                     "trsm+gemm", l11, a[j0 + nb:, j0:j0 + nb].T, None,
                     a[j0 + nb:, j0 + nb:], form="syrk", unit_diag=False,
-                    fuse=fuse, policy=pol, interpret=interpret,
-                    registry=registry)
+                    fuse=fuse, policy=pol, registry=registry)
                 a = a.at[j0 + nb:, j0:j0 + nb].set(x.T)
                 a = a.at[j0 + nb:, j0 + nb:].set(c_out)
     return jnp.tril(a)

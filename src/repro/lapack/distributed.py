@@ -68,8 +68,6 @@ def _shard_batched(mesh: Mesh, fn, a: jnp.ndarray, n_out: int):
 
 def batched_potrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
                   policy: Optional[str] = None,
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """Cholesky of a (B, n, n) SPD batch, batch-sharded over ``mesh``.
 
@@ -95,13 +93,12 @@ def batched_potrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
     mesh in {(1,1), (2,2), (4,2)}.
     """
     assert a.ndim == 3 and a.shape[1] == a.shape[2], a.shape
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     nb = _resolve_block(a.shape[1], block, "potrf", a.dtype)
     a_p, b0 = _pad_batch(a, _ndev(mesh))
 
     def local(x):
         return (_batched.batched_potrf(x, block=nb, policy=pol,
-                                       interpret=interpret,
                                        registry=registry).factors,)
 
     (factors,) = _shard_batched(mesh, local, a_p, 1)
@@ -110,8 +107,6 @@ def batched_potrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
 
 def batched_getrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
                   policy: Optional[str] = None,
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """LU with partial pivoting of a (B, m, n) batch, batch-sharded.
 
@@ -121,13 +116,13 @@ def batched_getrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
     ``tests/test_distributed_blas.py``.
     """
     assert a.ndim == 3, a.shape
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     nb = _resolve_block(min(a.shape[1], a.shape[2]), block, "getrf", a.dtype)
     a_p, b0 = _pad_batch(a, _ndev(mesh))
 
     def local(x):
         r = _batched.batched_getrf(x, block=nb, policy=pol,
-                                   interpret=interpret, registry=registry)
+                                   registry=registry)
         return r.factors, r.pivots
 
     factors, piv = _shard_batched(mesh, local, a_p, 2)
@@ -136,8 +131,6 @@ def batched_getrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
 
 def batched_geqrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
                   policy: Optional[str] = None,
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
                   registry=None) -> FactorizationResult:
     """Householder QR of a (B, m, n) batch, batch-sharded.
 
@@ -145,13 +138,13 @@ def batched_geqrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
     R/V factors + tau). Oracle: ``tests/test_distributed_blas.py``.
     """
     assert a.ndim == 3, a.shape
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     nb = _resolve_block(min(a.shape[1], a.shape[2]), block, "geqrf", a.dtype)
     a_p, b0 = _pad_batch(a, _ndev(mesh))
 
     def local(x):
         r = _batched.batched_geqrf(x, block=nb, policy=pol,
-                                   interpret=interpret, registry=registry)
+                                   registry=registry)
         return r.factors, r.tau
 
     factors, tau = _shard_batched(mesh, local, a_p, 2)
@@ -160,8 +153,6 @@ def batched_geqrf(a: jnp.ndarray, mesh: Mesh, block: Optional[int] = None,
 
 def batched_solve(res: FactorizationResult, b: jnp.ndarray, mesh: Mesh,
                   policy: Optional[str] = None,
-                  use_kernel: Optional[bool] = None,
-                  interpret: Optional[bool] = None,
                   registry=None) -> jnp.ndarray:
     """Solve A_i x_i = b_i for a batch-sharded FactorizationResult.
 
@@ -176,7 +167,7 @@ def batched_solve(res: FactorizationResult, b: jnp.ndarray, mesh: Mesh,
     Oracle: ``tests/test_distributed_blas.py`` (factor + solve round-trip
     vs the single-device path under ``dtype_tolerances``).
     """
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     ndev = _ndev(mesh)
     axes = _mesh_axes(mesh)
     b0 = res.factors.shape[0]
@@ -223,7 +214,7 @@ def batched_solve(res: FactorizationResult, b: jnp.ndarray, mesh: Mesh,
         lt = meta[0] if (tau is not None and piv is None) else None
         lres = FactorizationResult(f, lp, lt, res.kind, res.block)
         return _batched.batched_solve(lres, r, policy=pol,
-                                      interpret=interpret, registry=registry)
+                                      registry=registry)
 
     x = jax.shard_map(local, mesh=mesh, in_specs=tuple(in_specs),
                       out_specs=spec, check_vma=False)(*operands)

@@ -79,8 +79,7 @@ def getrf_unblocked(a: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 
 def getrf(a: jnp.ndarray, block: Optional[int] = None,
-          policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-          interpret: Optional[bool] = None, registry=None,
+          policy: Optional[str] = None, registry=None,
           fuse: Optional[bool] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Blocked right-looking LU with partial pivoting (LAPACK DGETRF).
 
@@ -101,8 +100,8 @@ def getrf(a: jnp.ndarray, block: Optional[int] = None,
     policy : {"reference", "model", "tuned"}, optional
         Trailing updates (TRSM for U12, GEMM for A22) dispatch through
         :mod:`repro.blas.level3`, resolved by :mod:`repro.tune.dispatch`:
-        ``"model"`` (deprecated ``use_kernel=True``) reaches the Pallas
-        MXU kernel, ``"tuned"`` the registry config.
+        ``"model"`` reaches the Pallas MXU kernel, ``"tuned"`` the
+        registry config.
     fuse : stream each trailing TRSM->GEMM pair through the fused
         ``trsm+gemm`` kernel? ``None`` defers to
         :func:`repro.core.codesign.plan_fused_chain` under the kernel
@@ -126,7 +125,7 @@ def getrf(a: jnp.ndarray, block: Optional[int] = None,
     """
     from repro.tune import dispatch as _tune
     from repro.tune.policy import resolve_policy
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     n, nc = a.shape
     kmax = min(n, nc)
     if block is None:
@@ -162,7 +161,7 @@ def getrf(a: jnp.ndarray, block: Optional[int] = None,
                     "trsm+gemm", l11, a[j0:j0 + nb, j0 + nb:],
                     a[j0 + nb:, j0:j0 + nb], a[j0 + nb:, j0 + nb:],
                     form="lu", unit_diag=True, fuse=fuse, policy=pol,
-                    interpret=interpret, registry=registry)
+                    registry=registry)
                 a = a.at[j0:j0 + nb, j0 + nb:].set(u12)
                 a = a.at[j0 + nb:, j0 + nb:].set(c_out)
     return a, jnp.concatenate(pivs)

@@ -101,8 +101,7 @@ def _larft(v: jnp.ndarray, tau: jnp.ndarray) -> jnp.ndarray:
 
 
 def geqrf(a: jnp.ndarray, block: Optional[int] = None,
-          policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-          interpret: Optional[bool] = None,
+          policy: Optional[str] = None,
           registry=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Blocked Householder QR, compact WY (LAPACK DGEQRF).
 
@@ -119,9 +118,8 @@ def geqrf(a: jnp.ndarray, block: Optional[int] = None,
     policy : {"reference", "model", "tuned"}, optional
         The trailing compact-WY triple product is three GEMMs dispatched
         through :func:`repro.blas.level3.gemm`, resolved by
-        :mod:`repro.tune.dispatch` (``"model"`` - the deprecated
-        ``use_kernel=True`` - is the Pallas MXU kernel, ``"tuned"`` the
-        registry config).
+        :mod:`repro.tune.dispatch` (``"model"`` is the Pallas MXU kernel,
+        ``"tuned"`` the registry config).
 
     Returns
     -------
@@ -135,7 +133,7 @@ def geqrf(a: jnp.ndarray, block: Optional[int] = None,
     agreement in ``tests/test_tune.py``.
     """
     from repro.tune.policy import resolve_policy
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     m, n = a.shape
     kmax = min(m, n)
     if block is None:
@@ -176,11 +174,11 @@ def geqrf(a: jnp.ndarray, block: Optional[int] = None,
                               1.0, V)
                 T = _larft(V, tau)
                 C = a[:, j0 + nb:]
-                W = gemm(V, C, transa=True, policy=pol, interpret=interpret,
+                W = gemm(V, C, transa=True, policy=pol,
                          registry=registry)           # (nb, rest)   GEMM
                 W = T.T @ W                           # small (nb x nb) GEMM
                 a = a.at[:, j0 + nb:].set(
-                    C - gemm(V, W, policy=pol, interpret=interpret,
+                    C - gemm(V, W, policy=pol,
                              registry=registry))      # GEMM
     return a, jnp.concatenate(taus)
 
@@ -206,14 +204,12 @@ def q_from_geqrf(packed: jnp.ndarray, tau: jnp.ndarray) -> jnp.ndarray:
 
 
 def qr(a: jnp.ndarray, block: Optional[int] = None,
-       policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-       interpret: Optional[bool] = None,
+       policy: Optional[str] = None,
        registry=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Convenience thin-QR: returns (Q (m, min(m,n)), R (min(m,n), n))
-    from :func:`geqrf` + :func:`q_from_geqrf`; same
-    block/policy/``use_kernel`` contract as :func:`geqrf`."""
-    packed, tau = geqrf(a, block=block, policy=policy, use_kernel=use_kernel,
-                        interpret=interpret, registry=registry)
+    from :func:`geqrf` + :func:`q_from_geqrf`; same block/policy contract
+    as :func:`geqrf`."""
+    packed, tau = geqrf(a, block=block, policy=policy, registry=registry)
     q = q_from_geqrf(packed, tau)
     r = jnp.triu(packed)[: min(a.shape), :]
     return q[:, : min(a.shape)], r

@@ -1,7 +1,7 @@
 """GESV-style dense solvers built on the factorizations.
 
 Both drivers thread the tuner policy (``reference`` | ``model`` |
-``tuned``; ``use_kernel`` deprecated alias) through every factorization
+``tuned``) through every factorization
 and triangular solve, so the whole solve resolves its kernel configs via
 :mod:`repro.tune.dispatch`.
 """
@@ -18,8 +18,7 @@ from repro.lapack.qr import geqrf, q_from_geqrf
 
 
 def gesv(a: jnp.ndarray, b: jnp.ndarray, block: Optional[int] = None,
-         policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-         interpret: Optional[bool] = None, registry=None) -> jnp.ndarray:
+         policy: Optional[str] = None, registry=None) -> jnp.ndarray:
     """Solve A X = B via LU with partial pivoting (LAPACK DGESV).
 
     Parameters
@@ -29,7 +28,7 @@ def gesv(a: jnp.ndarray, b: jnp.ndarray, block: Optional[int] = None,
     policy : {"reference", "model", "tuned"}, optional
         Threaded through the factorization and both triangular solves,
         so the whole solve resolves its kernel configs through
-        :mod:`repro.tune.dispatch`; ``use_kernel`` deprecated alias.
+        :mod:`repro.tune.dispatch`.
 
     Returns
     -------
@@ -40,22 +39,20 @@ def gesv(a: jnp.ndarray, b: jnp.ndarray, block: Optional[int] = None,
     Oracle: ``tests/test_lapack.py`` (vs ``np.linalg.solve``).
     """
     from repro.tune.policy import resolve_policy
-    pol = resolve_policy(policy, use_kernel)
-    packed, piv = getrf(a, block=block, policy=pol, interpret=interpret,
-                        registry=registry)
+    pol = resolve_policy(policy)
+    packed, piv = getrf(a, block=block, policy=pol, registry=registry)
     rhs = b if b.ndim == 2 else b[:, None]
     with _obs.span("gesv.getrs", cat="solve"):
         rhs = apply_ipiv(rhs, piv)
         y = trsm(packed, rhs, lower=True, unit_diag=True, left=True,
-                 policy=pol, interpret=interpret, registry=registry)
+                 policy=pol, registry=registry)
         x = trsm(packed, y, lower=False, unit_diag=False, left=True,
-                 policy=pol, interpret=interpret, registry=registry)
+                 policy=pol, registry=registry)
     return x if b.ndim == 2 else x[:, 0]
 
 
 def lstsq_qr(a: jnp.ndarray, b: jnp.ndarray, block: Optional[int] = None,
-             policy: Optional[str] = None, use_kernel: Optional[bool] = None,
-             interpret: Optional[bool] = None, registry=None) -> jnp.ndarray:
+             policy: Optional[str] = None, registry=None) -> jnp.ndarray:
     """Least-squares min ||A x - b|| via QR: x = R^{-1} Q^T b.
 
     Parameters
@@ -75,14 +72,13 @@ def lstsq_qr(a: jnp.ndarray, b: jnp.ndarray, block: Optional[int] = None,
     overdetermined systems).
     """
     from repro.tune.policy import resolve_policy
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     m, n = a.shape
-    packed, tau = geqrf(a, block=block, policy=pol, interpret=interpret,
-                        registry=registry)
+    packed, tau = geqrf(a, block=block, policy=pol, registry=registry)
     q = q_from_geqrf(packed, tau)
     rhs = b if b.ndim == 2 else b[:, None]
     qtb = q.T @ rhs
     r = jnp.triu(packed)[:n, :n]
     x = trsm(r, qtb[:n], lower=False, unit_diag=False, left=True,
-             policy=pol, interpret=interpret, registry=registry)
+             policy=pol, registry=registry)
     return x if b.ndim == 2 else x[:, 0]

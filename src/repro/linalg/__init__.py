@@ -29,9 +29,9 @@ mesh, registry, accumulation dtype - carried by a scoped
 
 Callers never pick a namespace by deployment shape: the same ``gemm``
 call runs plain jnp, the Pallas MXU kernel, a tuned registry config, or
-the SUMMA mesh schedule depending only on the active context. The old
-d-prefixed routines (``repro.blas.dgemm``, ...) survive as thin
-deprecation shims that forward here (see ``docs/migration.md``).
+the SUMMA mesh schedule depending only on the active context. Names
+removed from the stack, and what replaces them, are listed in
+``docs/migration.md``.
 """
 from repro.lapack.batched import FactorizationResult
 from repro.linalg.context import (UNSET, ExecutionContext, get_context,

@@ -4,7 +4,7 @@ Every routine here:
 
 * accepts float32/float64 operands (bfloat16 storage where the kernel
   path supports it) and an explicit ``dtype=`` cast,
-* resolves policy / registry / interpret / accumulation dtype from the
+* resolves policy / registry / accumulation dtype from the
   active :class:`repro.linalg.ExecutionContext` (``context=`` overrides
   per call),
 * routes to the distributed backend when the context carries a mesh
@@ -17,8 +17,7 @@ Every routine here:
 
 The numeric cores live in :mod:`repro.blas.level1`/``level2``/``level3``;
 this layer only resolves the context and casts dtypes, so a call under the
-default context is bit-identical to the deprecated d-prefixed routine it
-replaces.
+default context is bit-identical to the core it calls.
 """
 from __future__ import annotations
 
@@ -35,9 +34,9 @@ from repro.blas import level1 as _l1
 from repro.blas import level2 as _l2
 from repro.blas import level3 as _l3
 from repro.linalg.context import (current, resolved_accum_dtype,
-                                  resolved_interpret, resolved_machine,
-                                  resolved_mesh, resolved_obs,
-                                  resolved_policy, resolved_registry)
+                                  resolved_machine, resolved_mesh,
+                                  resolved_obs, resolved_policy,
+                                  resolved_registry)
 
 
 def _routine(op, info=None):
@@ -223,12 +222,11 @@ def _dtypes(ctx, dtype, *arrays):
 
     (None, None) - the passthrough fast path - means no explicit ``dtype``
     and no context accumulation dtype: operands reach the numeric core
-    untouched, so results are bitwise what the core produces (the
-    deprecation shims rely on this). Otherwise: storage = the explicit
-    ``dtype`` or the result type of *all* operands (accumulands like
-    ``c``/``y`` participate in the promotion, as they would in plain jnp);
-    compute = the context's accumulation dtype (upcast) or the storage
-    dtype.
+    untouched, so results are bitwise what the core produces. Otherwise:
+    storage = the explicit ``dtype`` or the result type of *all* operands
+    (accumulands like ``c``/``y`` participate in the promotion, as they
+    would in plain jnp); compute = the context's accumulation dtype
+    (upcast) or the storage dtype.
     """
     acc = resolved_accum_dtype(ctx)
     if dtype is None and acc is None:
@@ -250,8 +248,7 @@ def _cast(x, to):
 
 def _kw(ctx):
     """Context fields -> the kwargs every numeric core takes."""
-    return dict(policy=resolved_policy(ctx), interpret=resolved_interpret(ctx),
-                registry=resolved_registry(ctx))
+    return dict(policy=resolved_policy(ctx), registry=resolved_registry(ctx))
 
 
 # -------------------------------- level 3 -----------------------------------

@@ -1,12 +1,13 @@
 """Execution contexts: one scoped object replaces per-call kwarg threading.
 
 Every :mod:`repro.linalg` routine resolves an :class:`ExecutionContext`
-instead of taking ``policy=`` / ``use_kernel=`` / ``registry=`` kwargs.
-The context carries the *deployment shape* of a call:
+instead of taking ``policy=`` / ``registry=`` kwargs; the context is the
+one place a call's dispatch path is chosen. It carries the *deployment
+shape* of a call:
 
 ``policy``
     ``"reference" | "model" | "tuned"`` (``None`` = the process default,
-    i.e. ``REPRO_TUNE_POLICY`` or ``"reference"``).
+    ``"reference"``; :func:`set_context` changes it).
 ``mesh``
     ``None`` for single-device execution, or a ``jax.sharding.Mesh`` /
     ``(px, py)`` tuple. With a mesh set, routines that have a distributed
@@ -21,10 +22,6 @@ The context carries the *deployment shape* of a call:
     Optional accumulation dtype: operands are upcast to it for the
     computation and the result is cast back to the storage dtype. ``None``
     (the default) leaves numerics exactly as the operand dtype dictates.
-``interpret``
-    Run Pallas kernels in interpret mode. ``None`` (the default) takes it
-    from the device (:func:`repro.kernels.ops.interpret_mode`): interpret
-    exactly when the backend is not a TPU.
 ``machine``
     A :class:`repro.arch.MachineSpec` (or registered machine name) the
     call's planners and tuner lookups resolve against; ``None`` (the
@@ -51,6 +48,10 @@ the :data:`UNSET` sentinel). ``use`` scopes live in a
 :class:`contextvars.ContextVar`, so concurrent threads (and asyncio
 tasks) each see only their own scopes; :func:`set_context` replaces the
 process-global base underneath every scope.
+
+Whether the Pallas kernels interpret is not part of the context: the
+kernels take it from the device (:func:`repro.kernels.ops.interpret_mode`,
+interpret exactly when the backend is not a TPU).
 """
 from __future__ import annotations
 
@@ -79,8 +80,7 @@ class _UnsetType:
 
 UNSET = _UnsetType()
 
-_FIELDS = ("policy", "mesh", "registry", "accum_dtype", "interpret",
-           "machine", "obs")
+_FIELDS = ("policy", "mesh", "registry", "accum_dtype", "machine", "obs")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -91,7 +91,6 @@ class ExecutionContext:
     mesh: Any = UNSET
     registry: Any = UNSET
     accum_dtype: Any = UNSET
-    interpret: Any = UNSET
     machine: Any = UNSET
     obs: Any = UNSET
 
@@ -147,7 +146,6 @@ class ExecutionContext:
             reg_path = getattr(reg, "path", None)
         acc = None if self.accum_dtype in (UNSET, None) \
             else np.dtype(self.accum_dtype).name
-        interp = resolved_interpret(self)
         from repro import arch as _arch
         if self.machine in (UNSET, None):
             mach = _arch.current_machine().name
@@ -162,14 +160,12 @@ class ExecutionContext:
         else:
             obs_desc = getattr(self.obs, "name", "trace")
         return {"policy": pol, "mesh": mesh, "registry": reg_path,
-                "accum_dtype": acc, "interpret": interp, "machine": mach,
-                "obs": obs_desc}
+                "accum_dtype": acc, "machine": mach, "obs": obs_desc}
 
 
 # fully-resolved root: what a call sees with no context set anywhere
 _DEFAULT = ExecutionContext(policy=None, mesh=None, registry=None,
-                            accum_dtype=None, interpret=None, machine=None,
-                            obs=None)
+                            accum_dtype=None, machine=None, obs=None)
 # process-global base (set_context) + per-thread/task overlay scopes (use)
 _base = _DEFAULT
 _scopes: "contextvars.ContextVar[Tuple[ExecutionContext, ...]]" = \
@@ -243,32 +239,6 @@ def reset_context() -> None:
     _scopes.set(())
 
 
-def compat_context(policy=None, use_kernel=None,
-                   interpret: Optional[bool] = None, registry=None,
-                   use_pallas=None) -> ExecutionContext:
-    """Old kwarg triple -> per-call context (the d-prefixed shims' bridge).
-
-    Pins ``mesh=None``, ``accum_dtype=None``, and ``machine=None`` so a
-    deprecated call behaves exactly like the pre-:mod:`repro.linalg`
-    routine it shims - local execution, operand-dtype accumulation, and
-    no machine opinion of its own (``machine=None`` overrides any
-    enclosing context machine; planning falls back to the ambient
-    :func:`repro.arch.current_machine`, i.e. the process default unless
-    an explicit ``arch.machine_scope`` is active) - whatever context is
-    active. ``use_kernel`` / ``use_pallas`` go through
-    :func:`repro.tune.policy.resolve_policy`, which owns their own
-    deprecation warnings.
-    """
-    if policy is not None or use_kernel is not None or use_pallas is not None:
-        from repro.tune.policy import resolve_policy
-        pol = resolve_policy(policy, use_kernel, use_pallas)
-    else:
-        pol = UNSET
-    return ExecutionContext(
-        policy=pol, mesh=None, accum_dtype=None, interpret=interpret,
-        registry=registry if registry is not None else UNSET, machine=None)
-
-
 # ------------------------- lazy field normalizers ---------------------------
 
 _registry_cache: Dict[str, Any] = {}
@@ -304,14 +274,6 @@ def resolved_mesh(ctx: ExecutionContext):
 def resolved_policy(ctx: ExecutionContext):
     """ctx.policy as a policy-string-or-None (None = process default)."""
     return None if ctx.policy is UNSET else ctx.policy
-
-
-def resolved_interpret(ctx: ExecutionContext) -> bool:
-    """ctx.interpret as a bool; ``UNSET``/``None`` take the device's mode."""
-    if ctx.interpret is UNSET or ctx.interpret is None:
-        from repro.kernels.ops import interpret_mode
-        return interpret_mode()
-    return bool(ctx.interpret)
 
 
 def resolved_accum_dtype(ctx: ExecutionContext):

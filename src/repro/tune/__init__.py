@@ -16,29 +16,27 @@ This package is that loop, persisted:
 
 Policy semantics
 ================
-The public way to pick a policy is the :mod:`repro.linalg`
-ExecutionContext (``linalg.use(policy=...)``); underneath, every BLAS-3 /
-blocked-LAPACK numeric core takes ``policy``:
+The policy is picked in one place, the :mod:`repro.linalg`
+ExecutionContext (``linalg.use(policy=...)``, ``linalg.set_context``);
+the front end resolves it into the ``policy`` argument that every BLAS-3 /
+blocked-LAPACK numeric core takes:
 
 ``"reference"``
     Plain jnp (``a @ b``, scan substitutions). No Pallas, no registry.
-    This is the oracle path and the old ``use_kernel=False``.
+    This is the oracle path and the default.
 ``"model"``
     Pallas kernel with the analytically chosen config - ``plan_gemm`` /
     ``plan_trsm`` from :mod:`repro.core.codesign` (the paper's
-    pipeline-depth equation transplanted to block shapes). This is the old
-    ``use_kernel=True``.
+    pipeline-depth equation transplanted to block shapes).
 ``"tuned"``
     Pallas kernel with the *measured* best config from the registry, keyed
     by ``(op, shape-bucket, dtype, backend)``. A lookup miss (cold start,
     missing or corrupt registry file) falls back to exactly the ``model``
     resolution, so a cold-start ``tuned`` run is numerically identical to
-    ``model`` (and hence to the PR-1 ``use_kernel=True`` path).
+    ``model``.
 
-``use_kernel=True/False`` is kept everywhere as a *deprecated alias* for
-``policy="model"`` / ``policy="reference"``; an explicit ``policy`` wins.
-The default policy is ``"reference"`` and can be overridden with the
-``REPRO_TUNE_POLICY`` environment variable.
+Whether the Pallas kernels interpret is not a policy: the kernels take it
+from the device (:func:`repro.kernels.ops.interpret_mode`).
 
 Registry file format
 ====================

@@ -2,9 +2,10 @@
 
 ``resolve`` is the single place a (op, shape, dtype, backend, policy)
 tuple becomes an executable config; ``dispatch`` executes it. Every BLAS-3
-and blocked-LAPACK call in the repo funnels through here - the old
-``use_kernel`` booleans survive only as deprecated aliases that
-:func:`repro.tune.policy.resolve_policy` folds into a policy.
+and blocked-LAPACK call in the repo funnels through here. The policy is
+the caller's (the :mod:`repro.linalg` context resolves it); interpret mode
+is the kernels' own, from the device
+(:func:`repro.kernels.ops.interpret_mode`).
 
 Resolution table (``source`` records which row fired):
 
@@ -133,7 +134,7 @@ def _observed(res: "Resolution") -> "Resolution":
 
 
 def resolve(op: str, shape: Tuple[int, ...], dtype,
-            policy: Optional[str] = None, use_kernel: Optional[bool] = None,
+            policy: Optional[str] = None,
             registry: Optional[Registry] = None,
             backend: Optional[str] = None,
             mesh: Optional[Tuple[int, int]] = None,
@@ -165,7 +166,7 @@ def resolve(op: str, shape: Tuple[int, ...], dtype,
     mach = _arch.resolve_machine(machine)
     mach_str = _arch.machine_key_component(mach)
     mesh_str = f"x{mesh[0]}y{mesh[1]}" if (op == "pdgemm" and mesh) else None
-    pol = resolve_policy(policy, use_kernel)
+    pol = resolve_policy(policy)
     if not uses_kernel(pol):
         if op == "trsm":
             # the reference path still needs a diagonal width; 64 is the
@@ -260,7 +261,7 @@ def resolve(op: str, shape: Tuple[int, ...], dtype,
     return _observed(Resolution(op, pol, source, True, block=block, machine=mach.name))
 
 
-def _gemm_exec(a, b, res: Resolution, interpret: Optional[bool]):
+def _gemm_exec(a, b, res: Resolution):
     if not res.use_pallas or 0 in a.shape or 0 in b.shape:
         # degenerate operands (e.g. a wide-LU trailing block with no rows
         # left) cannot tile a Pallas grid; plain jnp handles empties
@@ -268,15 +269,12 @@ def _gemm_exec(a, b, res: Resolution, interpret: Optional[bool]):
     _counters.inc("kernel.launch")
     from repro.kernels import ops                   # lazy: kernels optional
     if b.ndim == 1:                                 # matvec through the MXU
-        return ops.gemm(a, b[:, None], plan=res.gemm_plan, use_pallas=True,
-                        interpret=interpret)[:, 0]
-    return ops.gemm(a, b, plan=res.gemm_plan, use_pallas=True,
-                    interpret=interpret)
+        return ops.gemm(a, b[:, None], plan=res.gemm_plan,
+                        use_pallas=True)[:, 0]
+    return ops.gemm(a, b, plan=res.gemm_plan, use_pallas=True)
 
 
 def dispatch(op: str, *args, policy: Optional[str] = None,
-             use_kernel: Optional[bool] = None,
-             interpret: Optional[bool] = None,
              registry: Optional[Registry] = None,
              machine: Optional[MachineSpec] = None, **kw):
     """One entry point for every BLAS-3 / blocked-LAPACK kernel call.
@@ -301,47 +299,44 @@ def dispatch(op: str, *args, policy: Optional[str] = None,
     """
     if machine is not None:
         with _arch.machine_scope(machine):
-            return dispatch(op, *args, policy=policy, use_kernel=use_kernel,
-                            interpret=interpret, registry=registry, **kw)
+            return dispatch(op, *args, policy=policy, registry=registry,
+                            **kw)
     if op == "gemm":
         a, b = args
         n_out = b.shape[1] if b.ndim == 2 else 1
         res = resolve("gemm", (a.shape[0], n_out, a.shape[1]), a.dtype,
-                      policy, use_kernel, registry)
-        return _gemm_exec(a, b, res, interpret)
+                      policy, registry)
+        return _gemm_exec(a, b, res)
     if op == "syrk":
         (a,) = args
         trans = kw.pop("trans", False)
         op_a = a.T if trans else a
         res = resolve("syrk", (op_a.shape[0], op_a.shape[0], op_a.shape[1]),
-                      a.dtype, policy, use_kernel, registry)
-        return _gemm_exec(op_a, op_a.T, res, interpret)
+                      a.dtype, policy, registry)
+        return _gemm_exec(op_a, op_a.T, res)
     if op == "gemv":
         a, x = args
         trans = kw.pop("trans", False)
         op_a = a.T if trans else a
-        res = resolve("gemv", op_a.shape, a.dtype, policy, use_kernel,
-                      registry)
+        res = resolve("gemv", op_a.shape, a.dtype, policy, registry)
         if not res.use_pallas:
             return op_a @ x
-        return _gemm_exec(op_a, x[:, None], res, interpret)[:, 0]
+        return _gemm_exec(op_a, x[:, None], res)[:, 0]
     if op == "trsm":
         a, b = args
         from repro.blas import level3               # lazy: avoid import cycle
-        return level3.trsm(a, b, policy=policy, use_kernel=use_kernel,
-                           interpret=interpret, registry=registry, **kw)
+        return level3.trsm(a, b, policy=policy, registry=registry, **kw)
     if op == "pdgemm":
         a, b = args
         from repro.blas import distributed          # lazy: avoid import cycle
-        return distributed.pdgemm(a, b, policy=policy, use_kernel=use_kernel,
-                                  interpret=interpret, registry=registry,
+        return distributed.pdgemm(a, b, policy=policy, registry=registry,
                                   **kw)
     if op == "gemm+epilogue":
         a, b = args
         bias = kw.pop("bias", None)
         epilogue = kw.pop("epilogue", "none")
         res = resolve("gemm+epilogue", (a.shape[0], b.shape[1], a.shape[1]),
-                      a.dtype, policy, use_kernel, registry,
+                      a.dtype, policy, registry,
                       epilogue=epilogue, has_bias=bias is not None)
         from repro.kernels import fused as _fk      # lazy: kernels optional
         if not res.use_pallas:
@@ -354,13 +349,11 @@ def dispatch(op: str, *args, policy: Optional[str] = None,
                                 * a.shape[1],
                                 bytes=res.chain.fused_hbm_bytes):
                 return _fk.gemm_bias_act(a, b, bias=bias, epilogue=epilogue,
-                                         plan=res.gemm_plan,
-                                         interpret=interpret)
+                                         plan=res.gemm_plan)
         # staged: the dispatcher GEMM kernel, then the epilogue as a
         # second pass over the HBM-resident product
         from repro.kernels import ops
-        out = ops.gemm(a, b, plan=res.gemm_plan, use_pallas=True,
-                       interpret=interpret)
+        out = ops.gemm(a, b, plan=res.gemm_plan, use_pallas=True)
         return _fk.apply_epilogue(out, epilogue, bias)
     if op == "trsm+gemm":
         l11, a_panel, b_left, c = args
@@ -368,7 +361,7 @@ def dispatch(op: str, *args, policy: Optional[str] = None,
         unit_diag = kw.pop("unit_diag", False)
         fuse = kw.pop("fuse", None)
         res = resolve("trsm+gemm", (c.shape[0], c.shape[1], l11.shape[0]),
-                      c.dtype, policy, use_kernel, registry, form=form)
+                      c.dtype, policy, registry, form=form)
         do_fuse = res.fused if fuse is None \
             else (bool(fuse) and res.use_pallas)
         if c.shape[0] == 0:
@@ -384,17 +377,14 @@ def dispatch(op: str, *args, policy: Optional[str] = None,
                                 bytes=res.chain.fused_hbm_bytes):
                 return _fk.trsm_gemm(l11, a_panel, b_left, c, form=form,
                                      unit_diag=unit_diag,
-                                     row_block=res.block,
-                                     interpret=interpret)
+                                     row_block=res.block)
         # staged dispatcher chain: TRSM then GEMM, X round-tripping HBM -
         # operation-for-operation the blocked drivers' historical trailing
         # update, so fuse=False keeps their numerics bitwise
         from repro.blas import level3               # lazy: avoid import cycle
         x = level3.trsm(l11, a_panel, lower=True, unit_diag=unit_diag,
-                        left=True, policy=res.policy, interpret=interpret,
-                        registry=registry)
+                        left=True, policy=res.policy, registry=registry)
         bl = x.T if form == "syrk" else b_left
-        upd = dispatch("gemm", bl, x, policy=res.policy, interpret=interpret,
-                       registry=registry)
+        upd = dispatch("gemm", bl, x, policy=res.policy, registry=registry)
         return x, c - upd
     raise ValueError(f"unknown op {op!r}; expected one of {OPS}")

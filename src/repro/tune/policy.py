@@ -1,15 +1,15 @@
-"""Dispatch policies and the deprecated ``use_kernel`` alias.
+"""Dispatch policies.
 
-``"reference"`` - plain jnp, the oracle path (old ``use_kernel=False``).
-``"model"``     - Pallas kernel, analytically planned config (old
-                  ``use_kernel=True``).
+``"reference"`` - plain jnp, the oracle path.
+``"model"``     - Pallas kernel, analytically planned config.
 ``"tuned"``     - Pallas kernel, measured config from the registry; cold
                   start falls back to the ``model`` resolution.
+
+The policy of a call comes from the :mod:`repro.linalg` ExecutionContext,
+which resolves it into each core's ``policy=`` argument.
 """
 from __future__ import annotations
 
-import os
-import warnings
 from typing import Optional
 
 POLICIES = ("reference", "model", "tuned")
@@ -17,54 +17,22 @@ POLICIES = ("reference", "model", "tuned")
 # policies whose execution path is the Pallas kernel
 KERNEL_POLICIES = ("model", "tuned")
 
-_ENV_POLICY = "REPRO_TUNE_POLICY"
-_warned_use_kernel = False
-_warned_use_pallas = False
-
 
 def default_policy() -> str:
-    """Process-wide default policy (env ``REPRO_TUNE_POLICY``, else
-    ``"reference"`` - the conservative oracle path)."""
-    pol = os.environ.get(_ENV_POLICY, "reference")
-    if pol not in POLICIES:
-        raise ValueError(
-            f"{_ENV_POLICY}={pol!r} is not one of {POLICIES}")
-    return pol
+    """The policy of a call that names none: ``"reference"``, the
+    conservative oracle path. ``repro.linalg.set_context(policy=...)``
+    changes the process default."""
+    return "reference"
 
 
-def resolve_policy(policy: Optional[str] = None,
-                   use_kernel: Optional[bool] = None,
-                   use_pallas: Optional[bool] = None) -> str:
-    """Collapse (policy, deprecated use_kernel/use_pallas) into one policy.
-
-    An explicit ``policy`` always wins. ``use_kernel`` (and its older
-    spelling ``use_pallas``) map True -> ``"model"`` and False ->
-    ``"reference"`` (their exact pre-tuner semantics); each alias warns
-    once per process. With none given, :func:`default_policy` applies.
-    """
-    global _warned_use_kernel, _warned_use_pallas
-    if policy is not None:
-        if policy not in POLICIES:
-            raise ValueError(f"unknown policy {policy!r}; expected one of "
-                             f"{POLICIES}")
-        return policy
-    if use_kernel is not None:
-        if not _warned_use_kernel:
-            warnings.warn(
-                "use_kernel is deprecated; pass policy='model' (True) or "
-                "policy='reference' (False) instead", DeprecationWarning,
-                stacklevel=3)
-            _warned_use_kernel = True
-        return "model" if use_kernel else "reference"
-    if use_pallas is not None:
-        if not _warned_use_pallas:
-            warnings.warn(
-                "use_pallas is deprecated; pass policy='model' (True) or "
-                "policy='reference' (False) instead", DeprecationWarning,
-                stacklevel=3)
-            _warned_use_pallas = True
-        return "model" if use_pallas else "reference"
-    return default_policy()
+def resolve_policy(policy: Optional[str] = None) -> str:
+    """Validate ``policy``; ``None`` takes :func:`default_policy`."""
+    if policy is None:
+        return default_policy()
+    if policy not in POLICIES:
+        raise ValueError(f"unknown policy {policy!r}; expected one of "
+                         f"{POLICIES}")
+    return policy
 
 
 def uses_kernel(policy: str) -> bool:

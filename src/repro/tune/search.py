@@ -130,8 +130,7 @@ _timeit = measure_wall_time
 
 def tune_gemm(m: int, n: int, k: int, dtype=jnp.float32,
               registry: Optional[Registry] = None, top_k: int = 3,
-              reps: int = 2, interpret: Optional[bool] = None,
-              seed: int = 0,
+              reps: int = 2, seed: int = 0,
               machine: Optional[MachineSpec] = None) -> SweepResult:
     """Sweep Pallas GEMM block shapes for one (m, n, k, dtype); record the
     measured winner in the registry keyed by the shape bucket (plus the
@@ -150,8 +149,8 @@ def tune_gemm(m: int, n: int, k: int, dtype=jnp.float32,
     measured = []
     best_i, best_t = 0, None
     for i, plan in enumerate(cands):
-        f = jax.jit(lambda x, y, p=plan: ops.gemm(
-            x, y, plan=p, use_pallas=True, interpret=interpret))
+        f = jax.jit(lambda x, y, p=plan: ops.gemm(x, y, plan=p,
+                                                  use_pallas=True))
         ms = _measure.measure(f, a, b, min_reps=reps, max_reps=2 * reps)
         t = ms.seconds_median
         model_s = model_score(plan, m, n, k, dtype.itemsize, machine=mach)
@@ -175,7 +174,7 @@ def tune_gemm(m: int, n: int, k: int, dtype=jnp.float32,
 def tune_fused_gemm(m: int, n: int, k: int, epilogue: str = "relu",
                     dtype=jnp.float32, has_bias: bool = True,
                     registry: Optional[Registry] = None, reps: int = 2,
-                    interpret: Optional[bool] = None, seed: int = 0,
+                    seed: int = 0,
                     machine: Optional[MachineSpec] = None) -> SweepResult:
     """Measure the fused GEMM+epilogue kernel against the staged chain
     (Pallas GEMM, then the epilogue as a separate jnp pass) at the chain
@@ -204,12 +203,12 @@ def tune_fused_gemm(m: int, n: int, k: int, epilogue: str = "relu",
             if has_bias else None)
 
     def staged(x, y, bb):
-        c = ops.gemm(x, y, plan=plan, use_pallas=True, interpret=interpret)
+        c = ops.gemm(x, y, plan=plan, use_pallas=True)
         return _fk.apply_epilogue(c, epilogue, bb)
 
     def fused_fn(x, y, bb):
         return _fk.gemm_bias_act(x, y, bias=bb, epilogue=epilogue,
-                                 plan=plan, interpret=interpret)
+                                 plan=plan)
 
     measured = []
     best_i, best_t = 0, None

@@ -376,16 +376,6 @@ def test_machine_name_string_in_context_validated():
         linalg.ExecutionContext(machine=123)
 
 
-def test_compat_context_pins_default_machine():
-    """Deprecation shims stay machine-agnostic: their pinned context maps
-    to the process-default machine even inside use(machine=...)."""
-    from repro.linalg.context import compat_context, resolved_machine
-    with linalg.use(machine="paper-pe"):
-        ctx = compat_context(policy="reference").over(linalg.get_context())
-        assert ctx.machine is None
-        assert resolved_machine(ctx) is None
-
-
 def test_cholesky_trailing_updates_see_context_machine(tmp_path):
     """The machine scope wraps the whole routine body: the trailing-update
     GEMMs inside a blocked factorization resolve under ctx.machine (probed

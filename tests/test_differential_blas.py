@@ -35,7 +35,7 @@ def _f64(x):
 def test_ddot_vs_numpy(rng, assert_close, n, schedule):
     x = _mk(rng, n, np.float32)
     y = _mk(rng, n, np.float32)
-    got = blas.ddot(x, y, schedule=schedule, accumulators=8)
+    got = blas.dot(x, y, schedule=schedule, accumulators=8)
     assert_close(got, np.dot(_f64(x), _f64(y)), scale=max(1.0, n / 64))
 
 
@@ -43,23 +43,23 @@ def test_ddot_vs_numpy(rng, assert_close, n, schedule):
 @pytest.mark.parametrize("n", VEC_NS)
 def test_daxpy_dscal_vs_numpy(rng, assert_close, n, dtype):
     x, y = _mk(rng, n, dtype), _mk(rng, n, dtype)
-    assert_close(blas.daxpy(2.5, x, y), 2.5 * _f64(x) + _f64(y))
-    assert_close(blas.dscal(-0.5, x), -0.5 * _f64(x))
+    assert_close(blas.axpy(2.5, x, y), 2.5 * _f64(x) + _f64(y))
+    assert_close(blas.scal(-0.5, x), -0.5 * _f64(x))
 
 
 @pytest.mark.parametrize("n", VEC_NS)
 def test_dnrm2_dasum_idamax_vs_numpy(rng, assert_close, n):
     x = _mk(rng, n, np.float32)
-    assert_close(blas.dnrm2(x), np.linalg.norm(_f64(x)))
-    assert_close(blas.level1.dasum(x), np.abs(_f64(x)).sum(),
+    assert_close(blas.nrm2(x), np.linalg.norm(_f64(x)))
+    assert_close(blas.level1.asum(x), np.abs(_f64(x)).sum(),
                  scale=max(1.0, n / 64))
-    assert int(blas.idamax(x)) == int(np.argmax(np.abs(_f64(x))))
+    assert int(blas.iamax(x)) == int(np.argmax(np.abs(_f64(x))))
 
 
 def test_drot_vs_oracle(rng, assert_close):
     x, y = _mk(rng, 33, np.float32), _mk(rng, 33, np.float32)
     c, s = np.cos(0.3), np.sin(0.3)
-    gx, gy = blas.level1.drot(x, y, c, s)
+    gx, gy = blas.level1.rot(x, y, c, s)
     assert_close(gx, c * _f64(x) + s * _f64(y))
     assert_close(gy, c * _f64(y) - s * _f64(x))
 
@@ -73,15 +73,15 @@ def test_dgemv_vs_numpy(rng, assert_close, m, n, trans):
     x = _mk(rng, m if trans else n, np.float32)
     y = _mk(rng, n if trans else m, np.float32)
     ref = (_f64(a).T if trans else _f64(a)) @ _f64(x)
-    assert_close(blas.dgemv(a, x, trans=trans), ref)
-    got = blas.dgemv(a, x, trans=trans, alpha=1.5, beta=-2.0, y=y)
+    assert_close(blas.gemv(a, x, trans=trans), ref)
+    got = blas.gemv(a, x, trans=trans, alpha=1.5, beta=-2.0, y=y)
     assert_close(got, 1.5 * ref - 2.0 * _f64(y))
 
 
 def test_dger_vs_numpy(rng, assert_close):
     x, y = _mk(rng, 13, np.float32), _mk(rng, 21, np.float32)
     a = _mk(rng, (13, 21), np.float32)
-    assert_close(blas.dger(0.75, x, y, a),
+    assert_close(blas.ger(0.75, x, y, a),
                  _f64(a) + 0.75 * np.outer(_f64(x), _f64(y)))
 
 
@@ -92,7 +92,7 @@ def test_dtrsv_vs_scipy(rng, assert_close, n, lower, unit_diag):
     a = _mk(rng, (n, n), np.float32)
     t = (jnp.tril(a) if lower else jnp.triu(a)) + 4 * jnp.eye(n)
     b = _mk(rng, n, np.float32)
-    got = blas.dtrsv(t, b, lower=lower, unit_diag=unit_diag)
+    got = blas.trsv(t, b, lower=lower, unit_diag=unit_diag)
     ref = scipy.linalg.solve_triangular(
         _f64(t), _f64(b), lower=lower, unit_diagonal=unit_diag)
     assert_close(got, ref, scale=4.0)
@@ -110,22 +110,22 @@ def test_dgemm_transpose_grid_vs_numpy(rng, assert_close, m, n, k, ta, tb,
     b = _mk(rng, (n, k) if tb else (k, n), dtype)
     opa, opb = (a.T if ta else a), (b.T if tb else b)
     ref = (_f64(a).T if ta else _f64(a)) @ (_f64(b).T if tb else _f64(b))
-    assert_close(blas.dgemm(opa, opb), ref, scale=max(1.0, k / 16))
+    assert_close(blas.gemm(opa, opb), ref, scale=max(1.0, k / 16))
 
 
 @pytest.mark.parametrize("m,n,k", [(24, 36, 12), (17, 5, 29)])
 def test_dgemm_alpha_beta_vs_numpy(rng, assert_close, m, n, k):
     a, b = _mk(rng, (m, k), np.float32), _mk(rng, (k, n), np.float32)
     c = _mk(rng, (m, n), np.float32)
-    got = blas.dgemm(a, b, c=c, alpha=-1.5, beta=0.5)
+    got = blas.gemm(a, b, c=c, alpha=-1.5, beta=0.5)
     assert_close(got, -1.5 * _f64(a) @ _f64(b) + 0.5 * _f64(c))
 
 
 @pytest.mark.parametrize("m,n,k", SHAPES_MM)
 def test_dgemm_kernel_path_vs_numpy(rng, assert_close, m, n, k):
-    """use_kernel=True (Pallas, interpret on CPU) against the same oracle."""
+    """policy="model" (Pallas, interpret on CPU) against the same oracle."""
     a, b = _mk(rng, (m, k), np.float32), _mk(rng, (k, n), np.float32)
-    got = blas.dgemm(a, b, use_kernel=True, interpret=True)
+    got = blas.gemm(a, b, policy="model")
     assert_close(got, _f64(a) @ _f64(b), scale=max(1.0, k / 16))
 
 
@@ -133,26 +133,26 @@ def test_dgemm_kernel_path_vs_numpy(rng, assert_close, m, n, k):
 def test_dsyrk_vs_numpy(rng, assert_close, lower):
     a = _mk(rng, (12, 20), np.float32)
     ref = _f64(a) @ _f64(a).T
-    assert_close(blas.dsyrk(a, lower=lower), ref)
+    assert_close(blas.syrk(a, lower=lower), ref)
     c = _mk(rng, (12, 12), np.float32)
-    got = blas.dsyrk(a, c=c, alpha=2.0, beta=-1.0, lower=lower,
-                     use_kernel=True)
+    got = blas.syrk(a, c=c, alpha=2.0, beta=-1.0, lower=lower,
+                    policy="model")
     # BLAS semantics: only the selected triangle of C is referenced
     tri = np.tril if lower else np.triu
     assert_close(tri(np.asarray(got)), tri(2.0 * ref - _f64(c)))
 
 
-@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("policy", ["reference", "model"])
 @pytest.mark.parametrize("left", [True, False])
 @pytest.mark.parametrize("lower", [True, False])
 @pytest.mark.parametrize("n,nrhs,block", [(24, 7, 8), (40, 3, 999)])
 def test_dtrsm_grid_vs_scipy(rng, assert_close, n, nrhs, block, lower, left,
-                             use_kernel):
+                             policy):
     a = _mk(rng, (n, n), np.float32)
     t = (jnp.tril(a) if lower else jnp.triu(a)) + 4 * jnp.eye(n)
     b = _mk(rng, (n, nrhs) if left else (nrhs, n), np.float32)
-    got = blas.dtrsm(t, b, lower=lower, left=left, block=block,
-                     use_kernel=use_kernel)
+    got = blas.trsm(t, b, lower=lower, left=left, block=block,
+                    policy=policy)
     if left:
         ref = scipy.linalg.solve_triangular(_f64(t), _f64(b), lower=lower)
     else:  # X T = B
@@ -256,13 +256,10 @@ def test_linalg_level1_dtype_grid(rng, assert_close, dtype):
 
 @pytest.mark.parametrize("pol", ["reference", "model", "tuned"])
 def test_linalg_policy_context_equals_kwarg_path(rng, pol):
-    """linalg under use(policy=...) must be bitwise the old per-call
-    policy= threading (the shims' path)."""
-    import warnings as _w
+    """linalg under use(policy=...) must be bitwise the numeric core
+    called with the same policy= argument."""
     a, b = _mk(rng, (24, 12), np.float32), _mk(rng, (12, 18), np.float32)
     with linalg.use(policy=pol):
         new = linalg.gemm(a, b)
-    with _w.catch_warnings():
-        _w.simplefilter("ignore", DeprecationWarning)
-        old = blas.dgemm(a, b, policy=pol)
+    old = blas.gemm(a, b, policy=pol)
     assert np.array_equal(np.asarray(new), np.asarray(old))

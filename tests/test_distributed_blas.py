@@ -55,7 +55,7 @@ def test_pdgemm_matches_dgemm_over_meshes_and_policies():
     for (m, n, k) in [(32, 32, 32), (24, 20, 36)]:
         a = jnp.asarray(rng.normal(size=(m, k)).astype(np.float32))
         b = jnp.asarray(rng.normal(size=(k, n)).astype(np.float32))
-        want = np.asarray(level3.dgemm(a, b, policy="reference"))
+        want = np.asarray(level3.gemm(a, b, policy="reference"))
         for px, py in MESHES:
             mesh = dblas.make_blas_mesh(px, py)
             for pol in POLICIES:
@@ -75,8 +75,8 @@ def test_pdgemm_epilogue_and_dispatch_route():
     b = jnp.asarray(rng.normal(size=(24, 16)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(16, 16)).astype(np.float32))
     mesh = dblas.make_blas_mesh(2, 2)
-    want = np.asarray(level3.dgemm(a, b, c=c, alpha=0.5, beta=-2.0,
-                                   policy="reference"))
+    want = np.asarray(level3.gemm(a, b, c=c, alpha=0.5, beta=-2.0,
+                                  policy="reference"))
     got = dblas.pdgemm(a, b, mesh, c=c, alpha=0.5, beta=-2.0,
                        policy="reference")
     close(got, want, scale=4.0)
@@ -97,8 +97,8 @@ def test_pdtrsm_matches_dtrsm():
     b = jnp.asarray(rng.normal(size=(n, nrhs)).astype(np.float32))
     for lower in (True, False):
         tt = t if lower else t.T
-        want = np.asarray(level3.dtrsm(tt, b, lower=lower,
-                                       policy="reference"))
+        want = np.asarray(level3.trsm(tt, b, lower=lower,
+                                      policy="reference"))
         for px, py in MESHES:
             mesh = dblas.make_blas_mesh(px, py)
             for pol in POLICIES:
@@ -107,13 +107,13 @@ def test_pdtrsm_matches_dtrsm():
                       msg=f"mesh=({px},{py}) lower={lower} policy={pol}")
     # right-side solve and 1-D rhs
     mesh = dblas.make_blas_mesh(4, 2)
-    want = np.asarray(level3.dtrsm(t, b.T, lower=True, left=False,
-                                   policy="reference"))
+    want = np.asarray(level3.trsm(t, b.T, lower=True, left=False,
+                                  policy="reference"))
     close(dblas.pdtrsm(t, b.T, mesh, lower=True, left=False,
                        policy="reference"), want, scale=8.0)
     v = b[:, 0]
     close(dblas.pdtrsm(t, v, mesh, policy="reference"),
-          np.asarray(level3.dtrsm(t, v[:, None], policy="reference"))[:, 0],
+          np.asarray(level3.trsm(t, v[:, None], policy="reference"))[:, 0],
           scale=8.0)
     print("pdtrsm differential OK")
     """)

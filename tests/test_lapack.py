@@ -6,7 +6,6 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro import lapack
-from repro.blas.level3 import dtrsm
 
 
 def _rand(rng, m, n):

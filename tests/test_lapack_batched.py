@@ -4,7 +4,7 @@ A == L L^T (potrf), P A == L U (getrf), A == Q R + Q orthonormal (geqrf),
 for both the blocked single-matrix paths and the vmap-batched drivers, on
 well-conditioned, ill-conditioned, and non-square inputs - and the blocked
 paths must produce identical factors whether trailing updates run through
-``a @ b`` or the Pallas kernel (use_kernel=True, interpret mode).
+``a @ b`` or the Pallas kernel (policy="model", interpret mode).
 """
 import jax
 import jax.numpy as jnp
@@ -61,23 +61,23 @@ def test_blocked_geqrf_roundtrip(rng, m, n):
 
 def test_potrf_kernel_path_identical(rng):
     s = _spd_batch(rng, 1, 48)[0]
-    ref = lapack.potrf(s, block=16, use_kernel=False)
-    ker = lapack.potrf(s, block=16, use_kernel=True, interpret=True)
+    ref = lapack.potrf(s, block=16, policy="reference")
+    ker = lapack.potrf(s, block=16, policy="model")
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=1e-5)
 
 
 def test_getrf_kernel_path_identical(rng):
     a = _batch(rng, 1, 48, 48)[0]
-    ref, piv_ref = lapack.getrf(a, block=16, use_kernel=False)
-    ker, piv_ker = lapack.getrf(a, block=16, use_kernel=True, interpret=True)
+    ref, piv_ref = lapack.getrf(a, block=16, policy="reference")
+    ker, piv_ker = lapack.getrf(a, block=16, policy="model")
     assert bool(jnp.all(piv_ref == piv_ker))
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=1e-5)
 
 
 def test_geqrf_kernel_path_identical(rng):
     a = _batch(rng, 1, 48, 32)[0]
-    ref, tau_ref = lapack.geqrf(a, block=16, use_kernel=False)
-    ker, tau_ker = lapack.geqrf(a, block=16, use_kernel=True, interpret=True)
+    ref, tau_ref = lapack.geqrf(a, block=16, policy="reference")
+    ker, tau_ker = lapack.geqrf(a, block=16, policy="model")
     np.testing.assert_allclose(np.asarray(tau_ker), np.asarray(tau_ref),
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), atol=1e-5)
@@ -125,8 +125,8 @@ def test_batched_geqrf_matches_unbatched(rng, m, n):
 def test_batched_kernel_path_matches(rng):
     """vmap composes with the Pallas interpret-mode trailing updates."""
     s = _spd_batch(rng, 3, 32)
-    ref = lapack.batched_potrf(s, block=16, use_kernel=False)
-    ker = lapack.batched_potrf(s, block=16, use_kernel=True, interpret=True)
+    ref = lapack.batched_potrf(s, block=16, policy="reference")
+    ker = lapack.batched_potrf(s, block=16, policy="model")
     np.testing.assert_allclose(np.asarray(ker.factors),
                                np.asarray(ref.factors), atol=1e-5)
 

@@ -167,7 +167,7 @@ def test_context_layering_and_overrides():
     assert linalg.get_context().policy == "model"
     with linalg.use(policy="tuned"):
         assert linalg.get_context().policy == "tuned"
-        with linalg.use(interpret=True):                # inherits policy
+        with linalg.use(accum_dtype=jnp.float32):       # inherits policy
             assert linalg.get_context().policy == "tuned"
         ctx = linalg.ExecutionContext(policy="reference")
         from repro.linalg.context import current
@@ -195,27 +195,20 @@ def test_context_describe_is_jsonable():
     d = ctx.describe()
     assert d == {"policy": "tuned", "mesh": [2, 2],
                  "registry": "/tmp/reg.json", "accum_dtype": "float32",
-                 "interpret": True, "machine": "tpu-like", "obs": None}
+                 "machine": "tpu-like", "obs": None}
     json.dumps(d)
     # defaults resolve to the process default policy
     assert linalg.get_context().describe()["policy"] == "reference"
 
 
 def test_default_context_interpret_comes_from_the_device(monkeypatch):
-    """The root context leaves interpret mode to the device: True on the
-    CPU backend, False on a TPU, and describe() reports the resolved
-    value. An explicit interpret still wins."""
+    """Interpret mode is the device's alone: the kernels interpret on the
+    CPU backend and compile on a TPU, and the context has no say in it."""
     from repro.kernels import ops
-    from repro.linalg.context import resolved_interpret
-    ctx = linalg.get_context()
-    assert ctx.interpret is None                         # not pinned
-    assert resolved_interpret(ctx) is True               # CPU backend
-    assert ctx.describe()["interpret"] is True
+    assert ops.interpret_mode() is True                  # CPU backend
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-    assert resolved_interpret(ctx) is False
-    assert ctx.describe()["interpret"] is False
-    with linalg.use(interpret=True):
-        assert linalg.get_context().describe()["interpret"] is True
+    assert ops.interpret_mode() is False
+    assert "interpret" not in linalg.get_context().describe()
 
 
 def test_compile_cache_dir_is_fixed(monkeypatch):
@@ -358,13 +351,11 @@ def test_linalg_grid_policy_mesh_dtype():
                     x = linalg.batched_solve(res, brhs)
                     close(jnp.einsum("bij,bj->bi", jnp.asarray(spd), x),
                           brhs, scale=256.0, msg="solve " + tag)
-        # d-prefixed shim == repro.linalg, bitwise, per dtype
-        import warnings
+        # numeric core == repro.linalg under the default context, bitwise,
+        # per dtype
         from repro import blas
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            old = blas.dgemm(a, b)
-        assert np.array_equal(np.asarray(old), np.asarray(linalg.gemm(a, b)))
+        core = blas.gemm(a, b)
+        assert np.array_equal(np.asarray(core), np.asarray(linalg.gemm(a, b)))
     print("linalg acceptance grid OK")
     """)
     r = subprocess.run([sys.executable, "-c", code], env=_ENV,

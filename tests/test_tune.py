@@ -4,7 +4,7 @@ Per-policy differential tests (ROADMAP convention): every dispatcher op
 must agree across ``reference`` / ``model`` / ``tuned`` within the shared
 ``dtype_tolerances`` (Pallas in interpret mode on CPU). Registry coverage:
 round-trip (write -> reload -> same config), corrupt/missing-file
-fallback, LRU eviction, and the deprecated ``use_kernel`` alias mapping.
+fallback, LRU eviction, and policy validation and defaults.
 """
 import json
 import os
@@ -40,7 +40,7 @@ def tmp_registry(tmp_path):
 @pytest.mark.parametrize("m,n,k", [(8, 8, 8), (24, 36, 12), (17, 5, 29)])
 def test_dgemm_policies_vs_numpy(rng, assert_close, m, n, k, pol):
     a, b = _mk(rng, (m, k)), _mk(rng, (k, n))
-    got = blas.dgemm(a, b, policy=pol, interpret=True)
+    got = blas.gemm(a, b, policy=pol)
     assert_close(got, _f64(a) @ _f64(b), scale=max(1.0, k / 16))
 
 
@@ -50,7 +50,7 @@ def test_dgemm_policies_vs_numpy(rng, assert_close, m, n, k, pol):
 def test_dgemm_transpose_flags(rng, assert_close, ta, tb, pol):
     a = _mk(rng, (12, 24) if ta else (24, 12))
     b = _mk(rng, (18, 12) if tb else (12, 18))
-    got = blas.dgemm(a, b, transa=ta, transb=tb, policy=pol, interpret=True)
+    got = blas.gemm(a, b, transa=ta, transb=tb, policy=pol)
     ref = (_f64(a).T if ta else _f64(a)) @ (_f64(b).T if tb else _f64(b))
     assert_close(got, ref)
 
@@ -60,7 +60,7 @@ def test_dgemm_transpose_flags(rng, assert_close, ta, tb, pol):
 def test_dsyrk_policies_reach_gemm_path(rng, assert_close, trans, pol):
     a = _mk(rng, (12, 20))
     op_a = _f64(a).T if trans else _f64(a)
-    got = blas.dsyrk(a, trans=trans, policy=pol, interpret=True)
+    got = blas.syrk(a, trans=trans, policy=pol)
     assert_close(got, op_a @ op_a.T)
 
 
@@ -68,7 +68,7 @@ def test_dsyrk_policies_reach_gemm_path(rng, assert_close, trans, pol):
 @pytest.mark.parametrize("trans", [False, True])
 def test_dgemv_policies_vs_numpy(rng, assert_close, trans, pol):
     a, x = _mk(rng, (17, 9)), _mk(rng, 17 if trans else 9)
-    got = blas.dgemv(a, x, trans=trans, policy=pol, interpret=True)
+    got = blas.gemv(a, x, trans=trans, policy=pol)
     assert_close(got, (_f64(a).T if trans else _f64(a)) @ _f64(x))
 
 
@@ -79,7 +79,7 @@ def test_dtrsm_policies_vs_scipy(rng, assert_close, lower, pol):
     a = _mk(rng, (n, n))
     t = (jnp.tril(a) if lower else jnp.triu(a)) + 4 * jnp.eye(n)
     b = _mk(rng, (n, 3))
-    got = blas.dtrsm(t, b, lower=lower, policy=pol, interpret=True)
+    got = blas.trsm(t, b, lower=lower, policy=pol)
     ref = scipy.linalg.solve_triangular(_f64(t), _f64(b), lower=lower)
     assert_close(got, ref, scale=4.0)
 
@@ -88,7 +88,7 @@ def test_dtrsm_policies_vs_scipy(rng, assert_close, lower, pol):
 def test_potrf_policies_agree(rng, assert_close, pol):
     a = _mk(rng, (48, 48))
     s = a @ a.T + 48 * jnp.eye(48)
-    got = lapack.potrf(s, block=16, policy=pol, interpret=True)
+    got = lapack.potrf(s, block=16, policy=pol)
     want = np.linalg.cholesky(_f64(s))
     assert_close(got, want, scale=8.0)
 
@@ -97,24 +97,24 @@ def test_potrf_policies_agree(rng, assert_close, pol):
 def test_gesv_policies_agree(rng, assert_close, pol):
     a = _mk(rng, (32, 32)) + 8 * jnp.eye(32)
     b = _mk(rng, (32, 2))
-    got = lapack.gesv(a, b, block=8, policy=pol, interpret=True)
+    got = lapack.gesv(a, b, block=8, policy=pol)
     assert_close(got, np.linalg.solve(_f64(a), _f64(b)), scale=8.0)
 
 
 def test_cold_start_tuned_identical_to_use_kernel_path(rng, tmp_path):
     """Acceptance: with no registry file, the tuned policy must produce
-    bitwise the numerics of the PR-1 use_kernel=True path."""
+    bitwise the numerics of the model policy (the planned kernel)."""
     empty = Registry(path=str(tmp_path / "never-written.json"))
     a, b = _mk(rng, (24, 12)), _mk(rng, (12, 18))
-    old = blas.dgemm(a, b, use_kernel=True, interpret=True)
-    new = blas.dgemm(a, b, policy="tuned", registry=empty, interpret=True)
+    old = blas.gemm(a, b, policy="model")
+    new = blas.gemm(a, b, policy="tuned", registry=empty)
     assert np.array_equal(np.asarray(old), np.asarray(new))
     s = a @ a.T + 24 * jnp.eye(24)
-    old_l = lapack.potrf(s, block=8, use_kernel=True, interpret=True)
+    old_l = lapack.potrf(s, block=8, policy="model")
     import repro.tune.registry as reg_mod
     reg_mod.set_default_registry(empty)
     try:
-        new_l = lapack.potrf(s, block=8, policy="tuned", interpret=True)
+        new_l = lapack.potrf(s, block=8, policy="tuned")
     finally:
         reg_mod.set_default_registry(None)
     assert np.array_equal(np.asarray(old_l), np.asarray(new_l))
@@ -144,21 +144,23 @@ def test_float64_on_tpu_resolves_to_xla(op, shape, pol):
     assert cpu.use_pallas                    # interpret mode handles f64
 
 
-def test_use_kernel_alias_mapping():
-    assert policy.resolve_policy("tuned", use_kernel=False) == "tuned"
-    assert policy.resolve_policy(None, use_kernel=True) == "model"
-    assert policy.resolve_policy(None, use_kernel=False) == "reference"
-    assert policy.resolve_policy(None, None) == "reference"
+def test_default_policy_env(rng):
+    """The default policy is "reference"; only the linalg context changes
+    what a routine resolves to."""
+    from repro import linalg
+    assert policy.default_policy() == "reference"
+    assert policy.resolve_policy(None) == "reference"
+    assert policy.resolve_policy("tuned") == "tuned"
     with pytest.raises(ValueError, match="unknown policy"):
         policy.resolve_policy("fastest")
-
-
-def test_default_policy_env(monkeypatch):
-    monkeypatch.setenv("REPRO_TUNE_POLICY", "model")
-    assert policy.default_policy() == "model"
-    monkeypatch.setenv("REPRO_TUNE_POLICY", "warp-speed")
-    with pytest.raises(ValueError):
-        policy.default_policy()
+    a, b = _mk(rng, (16, 16)), _mk(rng, (16, 16))
+    with dispatch.record_resolutions() as rec:
+        linalg.gemm(a, b)
+    assert [r.policy for r in rec] == ["reference"]
+    linalg.set_context(policy="model")
+    with dispatch.record_resolutions() as rec:
+        linalg.gemm(a, b)
+    assert [(r.policy, r.use_pallas) for r in rec] == [("model", True)]
 
 
 def test_resolve_sources(tmp_registry):
@@ -191,8 +193,7 @@ def test_gemv_tuned_shares_gemm_registry_entries(rng, assert_close,
                          registry=tmp_registry, backend="cpu")
     assert r.source == "registry" and r.gemm_plan.bm == 256
     a, x = _mk(rng, (24, 12)), _mk(rng, 12)
-    got = blas.dgemv(a, x, policy="tuned", registry=tmp_registry,
-                     interpret=True)
+    got = blas.gemv(a, x, policy="tuned", registry=tmp_registry)
     assert_close(got, _f64(a) @ _f64(x))
 
 
@@ -311,8 +312,7 @@ def test_tune_gemm_writes_registry_and_dispatch_uses_it(rng, assert_close,
     assert r.source == "registry"
     # numerics through the tuned config still match the oracle
     a, b = _mk(rng, (16, 16)), _mk(rng, (16, 16))
-    got = blas.dgemm(a, b, policy="tuned", registry=tmp_registry,
-                     interpret=True)
+    got = blas.gemm(a, b, policy="tuned", registry=tmp_registry)
     assert_close(got, _f64(a) @ _f64(b))
 
 
